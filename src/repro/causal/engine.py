@@ -28,6 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.causal.policy import CausalPolicy
 from repro.causal.results import ClassifyResult, Comparison, ComparisonMatrix
@@ -374,11 +375,12 @@ class CausalEngine:
         full-capacity slab — and patches the promoted rows' true values
         over their clipped residuals.
 
-        Known scale limit (ROADMAP): the gathered [A, m] int32 operand
-        is placed by the gather, so on a mesh-sharded slab the rim
-        still concentrates ~4x the alive u8 bytes on one device; a
-        shard-wise rim (wide rows replicated vs each row shard under
-        shard_map) would remove that.  Promoted rows contradict the §4
+        Known scale limit (ROADMAP): a Pallas kernel cannot be
+        partitioned automatically, so on a mesh-sharded slab the
+        gathered alive rows move to ONE mesh device and the rim runs
+        there, concentrating ~4x the alive u8 bytes on it; a shard-wise
+        rim (wide rows replicated vs each row shard under shard_map)
+        would remove that.  Promoted rows contradict the §4
         moving-window premise, so fleets sharded for scale should treat
         them as an eviction signal, not steady state."""
         # interpret/block-shape overrides carry over; a packed-engine
@@ -391,9 +393,11 @@ class CausalEngine:
         wide_rows = jnp.asarray(
             np.stack([slab.wide[int(s)] for s in widx]))
         jaidx = jnp.asarray(aidx)
-        alive_i32 = pack.unpack_rows(
-            jnp.take(slab.cells_u8, jaidx, axis=0),
-            jnp.take(slab.base, jaidx))
+        rows = (jnp.take(slab.cells_u8, jaidx, axis=0),
+                jnp.take(slab.base, jaidx))
+        if self.policy.mesh is not None:
+            rows = jax.device_put(rows, self.policy.mesh.devices.flat[0])
+        alive_i32 = pack.unpack_rows(*rows)
         wpos = {int(s): i for i, s in enumerate(aidx)}
         alive_i32 = alive_i32.at[
             jnp.asarray([wpos[int(s)] for s in widx])].set(wide_rows)
@@ -411,14 +415,19 @@ class CausalEngine:
         widx = self._alive_widx(slab, aidx)
         if widx.size == 0:
             return bulk
-        rim = self._wide_rim(slab, aidx, widx, **kw)
+        # the rim ran on one device: replicate it over the mesh to patch
+        # the sharded bulk
+        rim = jax.device_put(self._wide_rim(slab, aidx, widx, **kw),
+                             NamedSharding(self.policy.mesh, P()))
         jw = jnp.asarray(widx)
         jaidx = jnp.asarray(aidx)
-        P = int(widx.size)
+        n_wide = int(widx.size)
 
         def patch(mat, row_pa, col_pa):
-            rows_full = jnp.zeros((P, cap), bool).at[:, jaidx].set(row_pa)
-            cols_full = jnp.zeros((P, cap), bool).at[:, jaidx].set(col_pa)
+            rows_full = jnp.zeros((n_wide, cap), bool).at[:, jaidx].set(
+                row_pa)
+            cols_full = jnp.zeros((n_wide, cap), bool).at[:, jaidx].set(
+                col_pa)
             mat = jnp.asarray(mat, bool).at[jw, :].set(rows_full)
             return mat.at[:, jw].set(cols_full.T)
 

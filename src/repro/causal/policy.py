@@ -41,7 +41,9 @@ class CausalPolicy:
     autotune       consult the measured engine/block-shape table
                    (``kernels.autotune``); False = built-in defaults.
     interpret      force Pallas interpret mode (None = auto: interpret
-                   off-TPU so the same kernel bodies run on CPU).
+                   off-TPU so the same kernel bodies run on CPU; see
+                   ``kernels.template.resolve_interpret``, and
+                   ``kernels.ops.DISPATCHES`` for what each call ran).
     observer       ``repro.obs.Observer`` riding the policy: every
                    consumer (engine, registry, gossip, runtime,
                    serving) instruments itself through it.  None (the

@@ -24,7 +24,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import wire
@@ -60,11 +59,11 @@ def _digest_ring_fn(mesh, axis: str):
             out_b = jax.lax.dynamic_update_slice(out_b, cb, (src * nd,))
         return out_s, out_a, out_b
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         ring, mesh=mesh,
         in_specs=(P(axis),) * 3,
         out_specs=(P(),) * 3,
-        check_rep=False,     # replication holds by construction (full ring)
+        check_vma=False,     # replication holds by construction (full ring)
     ))
 
 

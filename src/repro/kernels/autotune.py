@@ -55,6 +55,11 @@ import os
 import time
 from pathlib import Path
 
+import jax
+from jax._src.pallas.mosaic.error_handling import MosaicError
+
+from repro.kernels.template import resolve_interpret
+
 __all__ = [
     "lookup",
     "key_for",
@@ -229,24 +234,28 @@ def predict_hybrid_cost(N: int, H: int, m: int, bn: int, bm: int,
                         interpret: bool) -> float:
     """Predicted seconds for one fused hot+tail hybrid classify.
 
-    The fused kernel runs a UNIFORM body (both the exact hot verdict and
-    the packed tail math execute every step, a select picks the valid
-    side), so hot and tail row-tiles cost alike per grid step; the
-    hybrid speedup the bench demonstrates comes from the smaller tail
-    geometry ``m`` an fp budget allows once the fp-binding hot sessions
-    are carried exactly — which this model sees through ``m``.  ``N`` is
-    the TOTAL row count, ``H`` of which are hot."""
+    Hot row-tiles run only the integer containment branch (their tail
+    operand index stays on block 0, so nothing new is fetched): they
+    cost the per-step overhead.  Tail row-tiles stream and compare a
+    packed tile.  The hybrid speedup the bench demonstrates comes from
+    the smaller tail geometry ``m`` an fp budget allows once the
+    fp-binding hot sessions are carried exactly — which this model sees
+    through ``m``.  ``N`` is the TOTAL row count, ``H`` of which are
+    hot."""
     c = _MODEL[_backend(interpret)]
     T = max(N - H, 1)
-    steps = (-(-H // bn) + -(-T // bn)) * (-(-m // bm))
-    return steps * (c["step_overhead"] + bn * bm * (c["elem"] + c["hbm"]))
+    mtiles = -(-m // bm)
+    hot_steps = -(-H // bn) * mtiles
+    tail_steps = -(-T // bn) * mtiles
+    return (hot_steps * c["step_overhead"]
+            + tail_steps * (c["step_overhead"]
+                            + bn * bm * (c["elem"] + c["hbm"])))
 
 
 def _host_serialized(interpret: bool) -> bool:
     """True when mesh devices are forced host-platform devices sharing
     the physical cores — collectives there buy zero parallel compute
     (the CI topology: XLA_FLAGS=--xla_force_host_platform_device_count)."""
-    import jax
     return interpret or jax.default_backend() == "cpu"
 
 
@@ -264,9 +273,8 @@ def predict_sharded_cost(strategy: str, N: int, m: int, shards: int,
     if shards == 1:
         strategy = "replicated"          # a 1-wide ring is the plain sweep
     if bi is None or bj is None:
-        # mirror the per-backend defaults ops._matrix_blocks falls back
-        # to: interpret wants few big steps, tpu must fit VMEM
-        bi = bj = 128 if interpret else 8
+        # the defaults ops._matrix_blocks falls back to on every backend
+        bi = bj = 128
     tri = predict_cost("tri", N, N, m, bi, bj, bm, interpret)
     if strategy == "replicated":
         gather = N * m * _MODEL[_backend(interpret)].get("hbm", 0.0) or \
@@ -302,12 +310,19 @@ def prune(candidates: list, predicted: list[float]) -> list:
 # measured sweeps
 # ---------------------------------------------------------------------------
 
+# What a candidate the backend cannot build raises, and nothing else:
+# the generator's VMEM refusal and Pallas block checks (ValueError), an
+# op with no lowering (NotImplementedError), and the chip compiler's
+# refusals (MosaicError, or a runtime error when VMEM runs out).
+CANDIDATE_REFUSED = (ValueError, NotImplementedError,
+                     jax.errors.JaxRuntimeError, MosaicError)
+
+
 def _divisor_blocks(size: int, want: tuple, mult: int) -> list:
     return [b for b in want if b % mult == 0 and b <= size and size % b == 0]
 
 
 def _measure(fn, reps: int = 3) -> float:
-    import jax
     jax.block_until_ready(jax.tree.leaves(fn()))     # warm / compile
     best = float("inf")
     for _ in range(reps):
@@ -351,10 +366,7 @@ def autotune_matrix(N: int, m: int, *, span: int = 30,
     The analytic model ranks the full grid first and only the top half
     is measured.  Pass ``explain={}`` to receive the predicted ranking,
     the survivor list, and the measured times for auditing."""
-    import jax
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     from repro.kernels import ops
     cells, base = _rand_packed(N, m, span)
     cells_i32 = cells.astype("int32")
@@ -384,7 +396,7 @@ def autotune_matrix(N: int, m: int, *, span: int = 30,
                     cells, base, engine=engine, bi=bi, bj=bj, bm=bm,
                     interpret=interpret, use_autotune=False)
             dt = _measure(fn)
-        except Exception as e:            # candidate invalid on this backend
+        except CANDIDATE_REFUSED as e:    # candidate invalid on this backend
             if verbose:
                 print(f"  matrix {engine} bi={bi} bm={bm}: FAILED {e}")
             continue
@@ -407,10 +419,7 @@ def autotune_matrix_sharded(N: int, m: int, shards: int, *, span: int = 30,
 
     Returns {"strategy", "bi", "bj", "bm", "us"} — the config
     ``ops._compare_matrix_packed_sharded`` dispatches on."""
-    import jax
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     from repro.kernels import ops
     from repro.launch.mesh import make_fleet_mesh
 
@@ -440,7 +449,7 @@ def autotune_matrix_sharded(N: int, m: int, shards: int, *, span: int = 30,
                 cells, base, mesh=mesh, axis="fleet", strategy=strategy,
                 uniform_base=True, interpret=interpret, use_autotune=False)
             dt = _measure(fn)
-        except Exception as e:
+        except CANDIDATE_REFUSED as e:
             if verbose:
                 print(f"  matrix_sharded {strategy} d={shards}: FAILED {e}")
             continue
@@ -460,11 +469,9 @@ def autotune_one_vs_many(N: int, m: int, *, span: int = 30,
                          interpret: bool | None = None,
                          verbose: bool = False,
                          explain: dict | None = None):
-    import jax
     import jax.numpy as jnp
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     from repro.kernels import ops
     cells, base = _rand_packed(N, m, span)
     q = cells[0].astype(jnp.int32)
@@ -496,7 +503,7 @@ def autotune_one_vs_many(N: int, m: int, *, span: int = 30,
             dt = _measure(lambda: ops._classify_vs_many_packed(
                 q, cells, base, bn=bn, bm=bm, interpret=interpret,
                 use_autotune=False))
-        except Exception:
+        except CANDIDATE_REFUSED:
             continue
         results.append({"engine": "packed", "bn": bn, "bm": bm,
                         "us": dt * 1e6})
@@ -518,12 +525,10 @@ def autotune_hybrid(N: int, m: int, *, hot: int | None = None,
     exact hot rows, the rest the packed bloom tail.  Winners land under
     ``key_for("hybrid", N, hot, m, ...)`` — the hot count rides in the
     M slot — matching the ``ops._hybrid_blocks`` lookup."""
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     from repro.kernels import ops
     hot = hot if hot is not None else max(8, N // 8)
     T = max(8, N - hot)
@@ -557,7 +562,7 @@ def autotune_hybrid(N: int, m: int, *, hot: int | None = None,
             dt = _measure(lambda: ops._classify_hybrid(
                 q, 32, meta, hsums, cells, base, bn=bn, bm=bm,
                 interpret=interpret, use_autotune=False))
-        except Exception:
+        except CANDIDATE_REFUSED:
             continue
         results.append({"engine": "hybrid", "bn": bn, "bm": bm,
                         "us": dt * 1e6})
@@ -583,7 +588,7 @@ def autotune_shapes(shapes, *, shard_counts=(), interpret: bool | None = None,
     from repro.obs import resolve
     obs = resolve(observer)
     out = {}
-    interp = interpret if interpret is not None else _is_interp()
+    interp = resolve_interpret(interpret)
 
     def swept(op, N, m, fn, **kw):
         before = dict(SEARCH_STATS)
@@ -639,11 +644,6 @@ def autotune_shapes(shapes, *, shard_counts=(), interpret: bool | None = None,
                     explain=explain),
                 shards=d)
     return out
-
-
-def _is_interp() -> bool:
-    import jax
-    return jax.default_backend() != "tpu"
 
 
 def _print_explain(explains: dict) -> str:

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused bloom-clock merge + compare + Eq. 3 fp rate.
+"""Pallas TPU kernel: fused bloom-clock merge + compare (+ Eq. 3 fp rate).
 
 The runtime's receive path (§3 step 3) needs, per message:
     merged   = max(A, B)                  (the new clock)
@@ -11,8 +11,9 @@ Done naively that is 5 separate HBM passes over the two cell arrays; all
 of them are trivially byte-bound, so fusing them into ONE read of each
 operand tile is a straight bandwidth win (~5x).  The m axis is tiled and
 reduced with the revisited-output accumulation pattern: flags and sums
-accumulate across m-tiles, and the fp rates are finalized with
-log1p/expm1-stable math on the last tile.
+accumulate across m-tiles.  The fp rates are a per-row function of the
+two sums, applied after the kernel by the reference expression
+(``kernels.ref.eq3_fp``).
 
 Grid: (B/bb, m/bm); the second axis revisits the per-batch outputs.
 """
@@ -24,48 +25,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.template import (_accumulate, _eq3_pairs, _flag, _row_sum,
+                                    _two_lanes)
+
 __all__ = ["bloom_compare_kernel", "bloom_merge_compare_pallas"]
 
 
-def bloom_compare_kernel(
-    a_ref, b_ref,
-    merged_ref, flags_ref, sums_ref, fp_ref,
-    *, n_mtiles: int, m: int,
-):
+def bloom_compare_kernel(a_ref, b_ref, merged_ref, flags_ref, sums_ref):
     j = pl.program_id(1)
     a = a_ref[...]            # [bb, bm] int32
     b = b_ref[...]
 
     merged_ref[...] = jnp.maximum(a, b)
-
-    # tile-local reductions (keep 2D: [bb, 1])
-    le = jnp.all(a <= b, axis=1, keepdims=True)
-    ge = jnp.all(a >= b, axis=1, keepdims=True)
-    sa = jnp.sum(a, axis=1, keepdims=True).astype(jnp.float32)
-    sb = jnp.sum(b, axis=1, keepdims=True).astype(jnp.float32)
-
-    @pl.when(j == 0)
-    def _init():
-        flags_ref[...] = jnp.concatenate([le, ge], axis=1).astype(jnp.int32)
-        sums_ref[...] = jnp.concatenate([sa, sb], axis=1)
-
-    @pl.when(j > 0)
-    def _acc():
-        prev_flags = flags_ref[...]
-        cur = jnp.concatenate([le, ge], axis=1).astype(jnp.int32)
-        flags_ref[...] = prev_flags & cur
-        sums_ref[...] = sums_ref[...] + jnp.concatenate([sa, sb], axis=1)
-
-    @pl.when(j == n_mtiles - 1)
-    def _finalize():
-        s = sums_ref[...]                     # [bb, 2] total ΣA, ΣB
-        log_q = jnp.log1p(-1.0 / m)
-        # fp(x_sum over y_sum) = exp(x * log(-expm1(y * log_q)))
-        inner_b = jnp.clip(-jnp.expm1(s[:, 1:2] * log_q), 1e-30, 1.0)
-        inner_a = jnp.clip(-jnp.expm1(s[:, 0:1] * log_q), 1e-30, 1.0)
-        fp_ab = jnp.exp(s[:, 0:1] * jnp.log(inner_b))   # P(A ⊆ B by chance)
-        fp_ba = jnp.exp(s[:, 1:2] * jnp.log(inner_a))
-        fp_ref[...] = jnp.concatenate([fp_ab, fp_ba], axis=1)
+    # all(a <= b) as the min of a 0/1 int tile (direct compares, no wrap)
+    flags = _two_lanes(
+        jnp.min(_flag(a <= b, jnp.int32), axis=1, keepdims=True),
+        jnp.min(_flag(a >= b, jnp.int32), axis=1, keepdims=True))
+    _accumulate(j, flags_ref, sums_ref, flags,
+                _two_lanes(_row_sum(a), _row_sum(b)))
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "bm", "m_true", "interpret"))
@@ -80,14 +57,9 @@ def bloom_merge_compare_pallas(
 ):
     B, m = a.shape
     assert a.shape == b.shape and m % bm == 0 and B % bb == 0
-    n_mtiles = m // bm
-    grid = (B // bb, n_mtiles)
-    kernel = functools.partial(
-        bloom_compare_kernel, n_mtiles=n_mtiles, m=m_true if m_true else m
-    )
-    merged, flags, sums, fp = pl.pallas_call(
-        kernel,
-        grid=grid,
+    merged, flags, sums = pl.pallas_call(
+        bloom_compare_kernel,
+        grid=(B // bb, m // bm),
         in_specs=[
             pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
             pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
@@ -97,14 +69,13 @@ def bloom_merge_compare_pallas(
             # per-batch reductions: revisited across j
             pl.BlockSpec((bb, 2), lambda i, j: (i, 0)),
             pl.BlockSpec((bb, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((bb, 2), lambda i, j: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, m), a.dtype),
             jax.ShapeDtypeStruct((B, 2), jnp.int32),
             jax.ShapeDtypeStruct((B, 2), jnp.float32),
-            jax.ShapeDtypeStruct((B, 2), jnp.float32),
         ],
         interpret=interpret,
     )(a, b)
-    return merged, flags, sums, fp
+    # fp[:, 0] = P(A ⊆ B by chance), fp[:, 1] = P(B ⊆ A)
+    return merged, flags, sums, _eq3_pairs(sums, m_true if m_true else m)

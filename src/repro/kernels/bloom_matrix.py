@@ -15,36 +15,38 @@ What the engines compute (the design, shared by every instance):
     grid (N/bn, m/bm); one query clock vs bn peers per step.  Dominance
     flags AND-accumulate and sums ADD-accumulate across m-tiles into
     per-peer [bn, 2] outputs; the Eq. 3 fp rates (both directions) are
-    finalized with log1p/expm1-stable math on the last m-tile.  One HBM
+    applied to the total sums after the kernel.  One HBM
     read of the peer slab total; the packed variant reads u8 residuals
     and widens in VMEM (+ per-slot int32 base).
 
 ``bloom_matrix_pallas``
     grid (N/bi, M/bj, m/bm); tiled all-pairs int32 compare with in-kernel
-    row sums (accumulated on the j == 0 stripe) and Eq. 3 fp(row -> col)
-    finalized as the outer product of stable-log factors; column sums
-    arrive as a cheap precomputed input.
+    row sums (accumulated on the j == 0 stripe); Eq. 3 fp(row -> col) is
+    the outer product of the row sums and the precomputed column sums,
+    applied after the kernel.
 
 ``bloom_matrix_tri_pallas``
     symmetric all-pairs over ONE u8 slab.  ``ge(i, j) == le(j, i)``, so
     only the block-upper-triangle is swept (scalar-prefetched block index
     lists drive the grid) and each tile computes BOTH directions from a
-    single int16 difference: ``le = max(d) <= 0``, ``ge = min(d) >= 0``.
-    Half the pairs, one pairwise intermediate, u8 HBM reads.
+    single int32 difference, swept in 8-row chunks: ``le = max(d) <= 0``,
+    ``ge = min(d) >= 0``.  Half the pairs, one pairwise intermediate, u8
+    HBM reads.
 
 ``bloom_matrix_packed_pallas``
     the same single-difference formulation on a full rectangle.
 
 ``bloom_matrix_mxu_pallas``
     MXU formulation: per-pair violation counts ``sum_m relu(a - b)`` as
-    ONE ``dot_general`` per tile via thermometer encoding; ``le`` iff the
+    one ``dot_general`` per threshold via thermometer encoding; ``le`` iff the
     count is zero, opposite direction by the rank-1 identity with row/col
     sums.  Exact in f32 (counts <= m * T << 2^24); selected only for
     narrow value spans (the regime §4 promises).
 
 Per-row bases (window offsets) are honored in all packed engines: folded
-in as a clipped [bi, bj] delta (clipping at ±(U8_MAX + 1) cannot change
-a verdict since residual differences are bounded by U8_MAX) or as a
+in as a clipped pair delta added to the reduced extremes (clipping at
+±(U8_MAX + 1) cannot change a verdict since residual differences are
+bounded by U8_MAX) or as a
 per-row shift before encoding; padded lanes are masked in-kernel where
 bases make zero-padding non-neutral.
 

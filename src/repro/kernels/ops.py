@@ -3,7 +3,8 @@
 Handles: probe-index precomputation (hashing), the shared pad-and-crop
 plan (``tile2d`` — every wrapper pads through it instead of duplicating
 padding logic), platform dispatch (interpret=True off-TPU so the SAME
-kernel bodies are exercised on CPU), engine selection for the
+kernel bodies are exercised on CPU; every dispatch records which mode
+it resolved to in ``DISPATCHES``), engine selection for the
 comparison kernels (packed-u8 triangle / rectangle / MXU thermometer /
 legacy int32 — consulted from the measured ``kernels.autotune`` table),
 and un-padding.
@@ -24,13 +25,13 @@ the same implementations, so their results are bit-identical.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import warnings
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.hashing import bloom_indices
@@ -47,6 +48,8 @@ from repro.kernels.bloom_matrix import (
 from repro.kernels.bloom_tick import bloom_tick_pallas
 from repro.kernels.generate import bloom_hybrid_classify_pallas
 from repro.kernels.pack import U8_MAX
+from repro.kernels.ref import eq3_fp
+from repro.kernels.template import resolve_interpret
 
 __all__ = [
     "tick",
@@ -68,15 +71,22 @@ __all__ = [
 LANE = 128  # TPU lane width
 
 # Most recent comparison dispatch decision (op, engine, block shapes),
-# recorded by the resolution helpers below.  Engine/block resolution is
-# host-side (never traced), so this is accurate per call; the
-# ``CausalEngine`` front-door snapshots it into result metadata and the
-# fleet benchmark records it so perf claims name the engine they
-# measured.
+# recorded by the resolution helpers below.
+# Engine/block resolution is host-side (never traced), so this is
+# accurate per call; the ``CausalEngine`` front-door snapshots it into
+# result metadata and the fleet benchmark records it so perf claims name
+# the engine they measured.
 LAST_DISPATCH: dict = {}
 
+# Every kernel dispatch of this process, counted by (op, engine,
+# interpret).  The jitted entry points (tick, merge_compare, the int32
+# one-vs-many) count once per trace.  A run meant for the chip checks
+# that no key ends in True.
+DISPATCHES: collections.Counter = collections.Counter()
 
-def _note_dispatch(op: str, engine: str, **blocks) -> None:
+
+def _note_dispatch(op: str, engine: str, interpret: bool, **blocks) -> None:
+    DISPATCHES[(op, engine, interpret)] += 1
     LAST_DISPATCH.clear()
     LAST_DISPATCH.update({"op": op, "engine": engine, **blocks})
 
@@ -86,8 +96,14 @@ MXU_SPAN_MAX = 64
 _MXU_SPAN_BUCKETS = (8, 16, 32, 64)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _row_align(interpret: bool, lanes: bool = False) -> int:
+    """Row padding grain of a slab entering a kernel.  On the chip a u8
+    tile needs 32 rows, and rows that become an output's lane axis (the
+    tri engine's square blocks, a rectangle's columns) need 128; the
+    interpreter only needs the 8-row sublane grain."""
+    if interpret:
+        return 8
+    return LANE if lanes else 32
 
 
 def pad_to(x: jax.Array, mult: int, axis: int, value=0) -> jax.Array:
@@ -158,8 +174,8 @@ def tick(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Batched bloom tick: E events per clock, k probes each."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
+    DISPATCHES[("tick", "pallas", interpret)] += 1
     B, m = cells.shape
     idx = bloom_indices(ev_hi, ev_lo, k, m)          # [B, E, k] uint32
     probes = idx.reshape(B, -1).astype(jnp.int32)    # [B, P], all < m
@@ -181,8 +197,8 @@ def merge_compare(
 ):
     """Fused receive-path op. Returns dict with merged cells, dominance
     flags, sums and Eq.3 fp rates (see bloom_compare.py)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
+    DISPATCHES[("merge_compare", "pallas", interpret)] += 1
     B, m = a.shape
     # zero padding perturbs neither dominance (0<=0) nor sums; Eq. 3 must
     # use the TRUE m, passed statically to the kernel.
@@ -221,12 +237,13 @@ def _classify_vs_many(
     total sums and Eq. 3 fp rates both directions.  Zero padding
     perturbs neither dominance nor sums; Eq. 3 uses the TRUE m.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
+    DISPATCHES[("one_vs_many", "i32", interpret)] += 1
     (m,) = q.shape
     N, mp_ = peers.shape
     assert m == mp_, (q.shape, peers.shape)
-    peers_p, bn_eff, bm_eff = tile2d(peers, bn, bm)
+    peers_p, bn_eff, bm_eff = tile2d(peers, bn, bm,
+                                     row_align=_row_align(interpret))
     q_p = pad_to(q[None, :], peers_p.shape[1], axis=1)
     flags, sums, fp = bloom_one_vs_many_pallas(
         q_p, peers_p, bn=bn_eff, bm=bm_eff, m_true=m, interpret=interpret
@@ -253,7 +270,7 @@ def _one_vs_many_blocks(N: int, m: int, bn, bm, interpret: bool,
     if bn is None or bm is None:
         cfg = (autotune.lookup("one_vs_many", N, N, m, interpret) or {}) \
             if use_table else {}
-        bn = bn or cfg.get("bn", 8 if not interpret else 128)
+        bn = bn or cfg.get("bn", 128 if interpret else 512)
         bm = bm or cfg.get("bm", 512)
     return bn, bm
 
@@ -262,7 +279,8 @@ def _one_vs_many_body(q, peers, base, bn, bm, m: int, interpret: bool):
     """Pad one packed slab (or one row shard of it) and run the kernel;
     shared by the unsharded and shard_map'ed classify paths."""
     nd = peers.shape[0]
-    peers_p, bn_eff, bm_eff = tile2d(peers, bn, bm)
+    peers_p, bn_eff, bm_eff = tile2d(peers, bn, bm,
+                                     row_align=_row_align(interpret))
     q_p = pad_to(q[None, :], peers_p.shape[1], axis=1)
     base_p = _pad_base(base, peers_p.shape[0])
     flags, sums, fp = bloom_one_vs_many_packed_pallas(
@@ -284,13 +302,12 @@ def _classify_vs_many_packed(
     """One-vs-many classify against a PACKED slab: u8 HBM reads, the
     per-row base is re-applied tile-locally in VMEM.  Same result dict
     as ``_classify_vs_many``."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     (m,) = q.shape
     N, mp_ = peers.shape
     assert m == mp_, (q.shape, peers.shape)
     bn, bm = _one_vs_many_blocks(N, m, bn, bm, interpret, use_autotune)
-    _note_dispatch("one_vs_many", "packed", bn=bn, bm=bm)
+    _note_dispatch("one_vs_many", "packed", interpret, bn=bn, bm=bm)
     flags, sums, fp = _one_vs_many_body(q, peers, base, bn, bm, m, interpret)
     return _classify_dict(flags, sums, fp, N)
 
@@ -317,8 +334,7 @@ def _classify_vs_many_packed_sharded(
     the Eq. 3 fp bits) is bit-identical across shard counts and vs the
     unsharded engine.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     (m,) = q.shape
     N, mp_ = peers.shape
     assert m == mp_, (q.shape, peers.shape)
@@ -326,8 +342,8 @@ def _classify_vs_many_packed_sharded(
     if N % shards:
         raise ValueError(f"slab rows {N} not divisible by {shards} shards")
     bn, bm = _one_vs_many_blocks(N, m, bn, bm, interpret, use_autotune)
-    _note_dispatch("one_vs_many", "packed_sharded", bn=bn, bm=bm,
-                   shards=shards)
+    _note_dispatch("one_vs_many", "packed_sharded", interpret, bn=bn,
+                   bm=bm, shards=shards)
     fn = _sharded_classify_fn(mesh, axis, bn, bm, m, interpret)
     flags, sums, fp = fn(q, peers, jnp.asarray(base, jnp.int32).reshape(-1))
     return _classify_dict(flags, sums, fp, N)
@@ -342,11 +358,11 @@ def _sharded_classify_fn(mesh, axis: str, bn: int, bm: int, m: int,
     def shard_body(qv, cu8, b):
         return _one_vs_many_body(qv, cu8, b, bn, bm, m, interpret)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), P(axis, None), P(axis)),
         out_specs=(P(axis, None),) * 3,
-        check_rep=False,     # no replication rule for pallas_call
+        check_vma=False,     # no replication rule for pallas_call
     ))
 
 
@@ -382,7 +398,7 @@ def _hybrid_blocks(N: int, H: int, m: int, bn, bm, interpret: bool,
     if bn is None or bm is None:
         cfg = (autotune.lookup("hybrid", N, H, m, interpret) or {}) \
             if use_table else {}
-        bn = bn or cfg.get("bn", 8 if not interpret else 128)
+        bn = bn or cfg.get("bn", 128 if interpret else 512)
         bm = bm or cfg.get("bm", 512)
     return bn, bm
 
@@ -409,8 +425,7 @@ def _classify_hybrid(
     bit-identical to a flat packed slab classified with the same bm.
     Returns the ``_classify_dict`` layout over H+T rows, hot first.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     (m,) = q.shape
     H = hot_meta.shape[0]
     T, mt_ = tail.shape
@@ -418,7 +433,8 @@ def _classify_hybrid(
     assert H > 0 and T > 0, "hybrid needs both a hot set and a tail " \
         "(route single-representation slabs through the plain engines)"
     bn, bm = _hybrid_blocks(H + T, H, m, bn, bm, interpret, use_autotune)
-    tail_p, bn_eff, bm_eff = tile2d(tail, bn, bm)
+    tail_p, bn_eff, bm_eff = tile2d(tail, bn, bm,
+                                    row_align=_row_align(interpret))
     q_p = pad_to(q[None, :], tail_p.shape[1], axis=1)
     base_p = _pad_base(tail_base, tail_p.shape[0])
     # pad hot rows to the tile grain with (v=0, n_private=0) filler —
@@ -427,8 +443,8 @@ def _classify_hybrid(
     hsum_p = pad_to(
         jnp.asarray(hot_sums, jnp.float32).reshape(-1, 1), bn_eff, axis=0)
     vloc = jnp.full((1, 1), v_local, jnp.int32)
-    _note_dispatch("hybrid", "fused_hot_tail", bn=bn_eff, bm=bm_eff,
-                   hot=H, tail=T)
+    _note_dispatch("hybrid", "fused_hot_tail", interpret, bn=bn_eff,
+                   bm=bm_eff, hot=H, tail=T)
     flags, sums, fp = bloom_hybrid_classify_pallas(
         q_p, vloc, meta_p, hsum_p, tail_p, base_p,
         bn=bn_eff, bm=bm_eff, m_true=m, interpret=interpret)
@@ -443,16 +459,11 @@ def _classify_hybrid(
 # all-pairs compare
 # ---------------------------------------------------------------------------
 
-_EQ3_CLIP = 1e-30
-
-
 @functools.partial(jax.jit, static_argnames=("m_true",))
 def _eq3_outer(row_sums, col_sums, m_true: int):
-    """Eq. 3 fp of "row happened-before col" as an outer product in log
-    space — identical expression to the reference / in-kernel finalize."""
-    log_q = jnp.log1p(-1.0 / m_true)
-    inner = jnp.clip(-jnp.expm1(col_sums[None, :] * log_q), _EQ3_CLIP, 1.0)
-    return jnp.exp(row_sums[:, None] * jnp.log(inner))
+    """Eq. 3 fp of "row happened-before col" as an outer product — the
+    reference expression every engine finalizes with."""
+    return eq3_fp(row_sums[:, None], col_sums[None, :], m_true)
 
 
 # public alias: the registry's sparse promoted-row assembly re-finalizes
@@ -466,18 +477,6 @@ def _packed_row_sums(cells_u8, base, m_true: int):
     s = jnp.sum(cells_u8.astype(jnp.int32), axis=1).astype(jnp.float32)
     return s + jnp.asarray(base, jnp.int32).reshape(-1).astype(jnp.float32) \
         * m_true
-
-
-@functools.partial(jax.jit, static_argnames=("n", "m", "m_true", "bi"))
-def _tri_combine(le, ge, row_sums, n: int, m: int, m_true: int, bi: int):
-    """Mirror the block-upper-triangle results onto the lower triangle
-    (le(i, j) == ge(j, i)), crop, and finalize sums/fp."""
-    k = le.shape[0] // bi
-    blk = jnp.arange(k).repeat(bi)
-    upper = blk[:, None] <= blk[None, :]
-    le_f = jnp.where(upper, le, ge.T)[:n, :m].astype(bool)
-    ge_f = jnp.where(upper, ge, le.T)[:n, :m].astype(bool)
-    return _matrix_dict(le_f, ge_f, row_sums, row_sums, m_true)
 
 
 def _matrix_dict(le, ge, row_sums, col_sums, m_true):
@@ -509,16 +508,13 @@ def _matrix_blocks(engine, N, M, m, bi, bj, bm, interpret,
         cfg = autotune.lookup("matrix", N, M, m, interpret) or {}
     if shards == 1 and cfg.get("engine") != engine:
         cfg = {}
-    if interpret:
-        dflt = {"tri": (128, 128, 512), "full": (128, 128, 512),
-                "mxu": (128, 128, 512), "i32": (128, 128, 512)}[engine]
-    else:
-        # keep the pairwise int16 difference (bi*bj*bm*2B) well inside VMEM
-        dflt = {"tri": (8, 8, 512), "full": (8, 128, 512),
-                "mxu": (128, 128, 128), "i32": (8, 128, 512)}[engine]
-    return (bi or cfg.get("bi", dflt[0]),
-            bj or cfg.get("bj", dflt[1]),
-            bm or cfg.get("bm", dflt[2]))
+    # square 128 blocks: lane-aligned flag outputs on the chip, and the
+    # 8-row int32 pairwise difference (8*bj*bm*4B) well inside VMEM; the
+    # chip's thermometer streams 128-cell m-tiles
+    dflt_bm = 128 if engine == "mxu" and not interpret else 512
+    return (bi or cfg.get("bi", 128),
+            bj or cfg.get("bj", 128),
+            bm or cfg.get("bm", dflt_bm))
 
 
 def _compare_matrix_packed(
@@ -541,8 +537,7 @@ def _compare_matrix_packed(
     triangle and mirror the rest by transposition.  Returns the same
     dict as ``_compare_matrix``.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     symmetric = cols is None
     if symmetric:
         cols, col_base = cells, base
@@ -570,18 +565,16 @@ def _compare_matrix_packed(
         uniform_base = bool((b == b[0]).all()) and bool((cb == b[0]).all())
     bi, bj, bm = _matrix_blocks(engine, N, M, m, bi, bj, bm, interpret,
                                 use_autotune)
-    _note_dispatch("matrix", engine, bi=bi, bj=bj, bm=bm)
+    _note_dispatch("matrix", engine, interpret, bi=bi, bj=bj, bm=bm)
 
     row_sums = _packed_row_sums(cells, base, m)
     col_sums = row_sums if symmetric else _packed_row_sums(cols, col_base, m)
 
     if engine == "tri":
-        cells_p, bi_eff, bm_eff = tile2d(cells, max(bi, bj), bm)
-        base_p = _pad_base(base, cells_p.shape[0])
-        le, ge = bloom_matrix_tri_pallas(
-            cells_p, base_p, bi=bi_eff, bm=bm_eff, m_true=m,
-            with_base=not uniform_base, interpret=interpret)
-        return _tri_combine(le, ge, row_sums, N, M, m, bi_eff)
+        le, ge = _tri_flags(cells, base, max(bi, bj), bm, m,
+                            not uniform_base, interpret)
+        return _matrix_dict(le.astype(bool), ge.astype(bool),
+                            row_sums, row_sums, m)
 
     if engine == "full":
         le, ge = _full_rect_flags(cells, base, cols, col_base, bi, bj, bm,
@@ -592,9 +585,8 @@ def _compare_matrix_packed(
     if engine == "mxu":
         lo, span = _logical_bounds(cells, base, cols, col_base)
         n_thr = _span_bucket(span)
-        rows_p, bi_eff, bm_eff = tile2d(cells, bi, bm)
-        cols_p, bj_eff, _ = tile2d(cols, bj, bm_eff)
-        cols_p = pad_to(cols_p, rows_p.shape[1], axis=1)
+        rows_p, cols_p, bi_eff, bj_eff, bm_eff = _rect_tiles(
+            cells, cols, bi, bj, bm, interpret)
         viol = bloom_matrix_mxu_pallas(
             rows_p, cols_p, _pad_base(base, rows_p.shape[0]),
             _pad_base(col_base, cols_p.shape[0]),
@@ -612,15 +604,26 @@ def _full_rect_flags(rows, row_base, cols, col_base, bi, bj, bm,
     unsharded "full" branch and every sharded ring step (duplicate pads
     CSE away under jit).  Returns (le, ge) cropped to the true [N, M]."""
     N, M = rows.shape[0], cols.shape[0]
-    rows_p, bi_eff, bm_eff = tile2d(rows, bi, bm)
-    cols_p, bj_eff, _ = tile2d(cols, bj, bm_eff)
-    cols_p = pad_to(cols_p, rows_p.shape[1], axis=1)
+    rows_p, cols_p, bi_eff, bj_eff, bm_eff = _rect_tiles(
+        rows, cols, bi, bj, bm, interpret)
     le, ge = bloom_matrix_packed_pallas(
         rows_p, cols_p, _pad_base(row_base, rows_p.shape[0]),
         _pad_base(col_base, cols_p.shape[0]),
         bi=bi_eff, bj=bj_eff, bm=bm_eff, m_true=m,
         with_base=with_base, interpret=interpret)
     return le[:N, :M], ge[:N, :M]
+
+
+def _rect_tiles(rows, cols, bi, bj, bm, interpret: bool):
+    """Pad a (rows, cols) slab pair for a rectangle engine: the columns
+    land on the output's lane axis.  Returns (rows_p, cols_p, bi, bj,
+    bm) with effective blocks."""
+    rows_p, bi_eff, bm_eff = tile2d(rows, bi, bm,
+                                    row_align=_row_align(interpret))
+    cols_p, bj_eff, _ = tile2d(cols, bj, bm_eff,
+                               row_align=_row_align(interpret, lanes=True))
+    cols_p = pad_to(cols_p, rows_p.shape[1], axis=1)
+    return rows_p, cols_p, bi_eff, bj_eff, bm_eff
 
 
 def _compare_matrix_packed_sharded(
@@ -689,8 +692,7 @@ def _compare_matrix_packed_sharded(
     back (the fully-alive packed fast path) pass False so the
     replicated strategy skips a pointless [N, N] x 4 reshard.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     # every engine name valid elsewhere is accepted so sharding a
     # registry never breaks existing all_pairs(**kw) call sites: "tri"
     # has no per-tile meaning on the ring (off-diagonal tiles are
@@ -734,15 +736,15 @@ def _compare_matrix_packed_sharded(
                    for k, v in out.items()}
         _note_dispatch("matrix",
                        f"replicated_{inner.get('engine', 'tri')}",
-                       bi=inner.get("bi"), bj=inner.get("bj"),
+                       interpret, bi=inner.get("bi"), bj=inner.get("bj"),
                        bm=inner.get("bm"), shards=d, strategy="replicated")
         return out
     if strategy != "ring":
         raise ValueError(f"unknown sharded strategy: {strategy}")
     bi, bj, bm = _matrix_blocks("full", N, N, m, bi, bj, bm,
                                 interpret, use_autotune, shards=d)
-    _note_dispatch("matrix", "ring_full", bi=bi, bj=bj, bm=bm, shards=d,
-                   strategy="ring")
+    _note_dispatch("matrix", "ring_full", interpret, bi=bi, bj=bj, bm=bm,
+                   shards=d, strategy="ring")
     fn = _sharded_ring_fn(mesh, axis, N, bi, bj, bm, m, with_base, interpret)
     le, ge = fn(cells, base)
     row_sums = _packed_row_sums(cells, base, m)
@@ -770,12 +772,16 @@ def _gathered_replica(cells, dev):
     return gathered
 
 
+@functools.partial(jax.jit, static_argnames=("bi", "bm", "m", "with_base",
+                                             "interpret"))
 def _tri_flags(cells, b, bi, bm, m: int, with_base: bool, interpret: bool):
-    """Triangle-sweep flags for one symmetric block, mirrored locally
-    (``le(i, j) == ge(j, i)``) and cropped — the per-device diagonal
-    step of the ring, at half the pairwise work of a full rectangle."""
+    """Triangle-sweep flags for one symmetric slab, mirrored onto the
+    lower triangle (``le(i, j) == ge(j, i)``) and cropped — the
+    single-device tri engine and the ring's per-device diagonal step,
+    at half the pairwise work of a full rectangle."""
     n = cells.shape[0]
-    cells_p, bi_eff, bm_eff = tile2d(cells, bi, bm)
+    cells_p, bi_eff, bm_eff = tile2d(
+        cells, bi, bm, row_align=_row_align(interpret, lanes=True))
     le, ge = bloom_matrix_tri_pallas(
         cells_p, _pad_base(b, cells_p.shape[0]), bi=bi_eff, bm=bm_eff,
         m_true=m, with_base=with_base, interpret=interpret)
@@ -886,11 +892,11 @@ def _sharded_ring_fn(mesh, axis: str, N: int, bi: int, bj: int, bm: int,
                     ge_acc, ge_m, (0, mirror * nd))
         return le_acc, ge_acc
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         ring, mesh=mesh,
         in_specs=(P(axis, None), P(axis)),
         out_specs=(P(axis, None),) * 2,
-        check_rep=False,     # no replication rule for pallas_call
+        check_vma=False,     # no replication rule for pallas_call
     ))
 
 
@@ -906,10 +912,11 @@ def _logical_bounds(cells, base, cols, col_base):
 
 
 def _mxu_viable(cells, base, cols, col_base) -> bool:
-    try:
-        _, span = _logical_bounds(cells, base, cols, col_base)
-    except Exception:
+    """The thermometer needs a concrete value span: under an outer jit
+    the host-synced bounds probe cannot run, so the engine is out."""
+    if isinstance(cells, jax.core.Tracer):
         return False
+    _, span = _logical_bounds(cells, base, cols, col_base)
     return span <= MXU_SPAN_MAX
 
 
@@ -950,8 +957,7 @@ def _compare_matrix(
     flag matrices, the Eq. 3 ``fp`` of "row before col", and the
     per-row / per-col sums.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     symmetric = rows is cols
     N, m = rows.shape
     M, mc = cols.shape
@@ -986,11 +992,10 @@ def _compare_matrix(
 
     bi, bj, bm = _matrix_blocks("i32", N, M, m, bi, bj, bm, interpret,
                                 use_autotune)
-    _note_dispatch("matrix", "i32", bi=bi, bj=bj, bm=bm)
+    _note_dispatch("matrix", "i32", interpret, bi=bi, bj=bj, bm=bm)
     col_sums = jnp.sum(cols, axis=1).astype(jnp.float32)           # [M]
-    rows_p, bi_eff, bm_eff = tile2d(rows, bi, bm)
-    cols_p, bj_eff, _ = tile2d(cols, bj, bm_eff)
-    cols_p = pad_to(cols_p, rows_p.shape[1], axis=1)
+    rows_p, cols_p, bi_eff, bj_eff, bm_eff = _rect_tiles(
+        rows, cols, bi, bj, bm, interpret)
     col_sums_p = pad_to(col_sums[None, :], cols_p.shape[0], axis=1)
     le, ge, row_sums, fp = bloom_matrix_pallas(
         rows_p, cols_p, col_sums_p,

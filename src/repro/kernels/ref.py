@@ -4,7 +4,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["bloom_tick_ref", "bloom_merge_compare_ref"]
+__all__ = ["bloom_tick_ref", "bloom_merge_compare_ref", "eq3_fp"]
+
+_EQ3_CLIP = 1e-30
+
+
+def eq3_fp(x_sum, y_sum, m: int):
+    """Eq. 3: the chance that a clock with ``x_sum`` total increments
+    sits <= one with ``y_sum`` by coincidence, over m cells.
+
+    ``exp(x * log(1 - (1 - 1/m)^y))`` in log1p/expm1-stable form.  This
+    is THE expression: every kernel wrapper finalizes its sums through
+    it, so kernel fp is the reference fp by construction."""
+    log_q = jnp.log1p(-1.0 / m)
+    inner = jnp.clip(-jnp.expm1(y_sum * log_q), _EQ3_CLIP, 1.0)
+    return jnp.exp(x_sum * jnp.log(inner))
 
 
 def bloom_tick_ref(cells: jax.Array, probes: jax.Array) -> jax.Array:
@@ -30,11 +44,8 @@ def bloom_merge_compare_ref(a: jax.Array, b: jax.Array):
     ge = jnp.all(a >= b, axis=-1)
     sa = jnp.sum(a, axis=-1).astype(jnp.float32)
     sb = jnp.sum(b, axis=-1).astype(jnp.float32)
-    log_q = jnp.log1p(-1.0 / m)
-    inner_b = jnp.clip(-jnp.expm1(sb * log_q), 1e-30, 1.0)
-    inner_a = jnp.clip(-jnp.expm1(sa * log_q), 1e-30, 1.0)
-    fp_ab = jnp.exp(sa * jnp.log(inner_b))
-    fp_ba = jnp.exp(sb * jnp.log(inner_a))
+    fp_ab = eq3_fp(sa, sb, m)
+    fp_ba = eq3_fp(sb, sa, m)
     flags = jnp.stack([le, ge], axis=-1).astype(jnp.int32)
     sums = jnp.stack([sa, sb], axis=-1)
     fp = jnp.stack([fp_ab, fp_ba], axis=-1)

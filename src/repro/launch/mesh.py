@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.sharding import FLEET_AXIS
 
@@ -20,7 +20,7 @@ __all__ = ["make_production_mesh", "make_local_mesh", "make_fleet_mesh",
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -28,7 +28,13 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape: tuple, axes: tuple) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the sharding rules place arrays
+    with ``with_sharding_constraint``, which Explicit axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_fleet_mesh(shards: int | None = None, axis: str = FLEET_AXIS) -> Mesh:

@@ -294,6 +294,8 @@ def main(argv=None) -> int:
     if args.smoke is not None and args.rounds < 2:
         ap.error("--smoke needs --rounds >= 2 (round 1 asserts the "
                  "converged fleet's delta phase is empty)")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return _serve(args) if args.serve else _smoke(args)
 
 

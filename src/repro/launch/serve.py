@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.causal import CausalPolicy
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.params import init_params
 from repro.runtime.clock_runtime import ClockConfig
 from repro.serving.engine import ServeConfig, ServingEngine
@@ -60,6 +61,7 @@ def main():
                          "and exit; heavier runs via "
                          "benchmarks/bench_serve.py")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.bench_serve:
         import json

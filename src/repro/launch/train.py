@@ -24,6 +24,7 @@ from repro.causal import CausalPolicy
 from repro.configs import get_config, get_smoke_config
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim.adamw import OptConfig
 from repro.runtime.clock_runtime import ClockConfig, ClockRuntime
@@ -123,6 +124,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--inject-failure", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     train_loop(args)
 
 

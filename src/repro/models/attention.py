@@ -40,7 +40,6 @@ def decode_attention_split_kv(q, k, v, *, kv_valid, window, q_pos, mesh,
     q: [B, 1, H, Dh] (replicated inside — it is tiny);
     k/v: [B, Skv, KV, Dh] with Skv sharded over ``axis``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, Sq, H, Dh = q.shape
@@ -75,12 +74,12 @@ def decode_attention_split_kv(q, k, v, *, kv_valid, window, q_pos, mesh,
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
     dp = dp if B % max(1, __import__("math").prod(
         mesh.shape[a] for a in dp)) == 0 else None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, axis, None, None),
                   P(dp, axis, None, None), P(), P(), P()),
         out_specs=P(dp, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, jnp.asarray(kv_valid), jnp.asarray(window),
       jnp.asarray(q_pos))
 
@@ -192,7 +191,6 @@ def _sharded_slot_update(buf_arr, new_row, slot, mesh, axis: str = "model"):
     qwen110b decode).  Instead each shard checks whether it owns ``slot``
     and updates locally — zero collectives.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     import math
@@ -211,12 +209,12 @@ def _sharded_slot_update(buf_arr, new_row, slot, mesh, axis: str = "model"):
         return jnp.where(inside, upd, b_loc)
 
     nd = buf_arr.ndim
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, axis, *([None] * (nd - 2))),
                   P(dp, *([None] * (nd - 1))), P()),
         out_specs=P(dp, axis, *([None] * (nd - 2))),
-        check_rep=False,
+        check_vma=False,
     )(buf_arr, new_row, jnp.asarray(slot))
 
 

@@ -130,8 +130,6 @@ def _moe_alltoall(params: dict, cfg: ModelConfig, x2d: jax.Array,
     its own token shard (no redundant routing across the model axis), then
     all_to_all over ep_axis moves expert buckets to their owners.
     """
-    from jax.experimental.shard_map import shard_map
-
     ep = mesh.shape[ep_axis]
     E_phys = cfg.n_experts * cfg.moe_replicas
     assert E_phys % ep == 0, (E_phys, ep, "pick moe_replicas so ep | E_phys")
@@ -159,13 +157,13 @@ def _moe_alltoall(params: dict, cfg: ModelConfig, x2d: jax.Array,
 
     token_axes = tuple(dp_axes) + (ep_axis,)
     dp_spec = P(token_axes, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(dp_spec, P(None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None)),
         out_specs=(dp_spec, P(token_axes)),
-        check_rep=False,
+        check_vma=False,
     )(x2d, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
     return y, jnp.mean(aux)

@@ -47,6 +47,7 @@ from repro.fleet.registry import (ClockRegistry, EvictedRow, FleetView,
                                   STATUS_NAMES, _near_wrap,
                                   view_from_classify)
 from repro.kernels import ops
+from repro.kernels.template import resolve_interpret
 from repro.obs.observer import resolve
 
 __all__ = ["TierConfig", "TieredRegistry", "TieredView"]
@@ -130,8 +131,7 @@ class TieredRegistry:
         # block (f32 accumulation order), and the autotune table is
         # keyed by slab N — per-tier resolution could tile m differently
         # per tier and break the flat-slab bit-identity contract.
-        interpret = (base_pol.interpret if base_pol.interpret is not None
-                     else not ops._on_tpu())
+        interpret = resolve_interpret(base_pol.interpret)
         bn, bm = ops._one_vs_many_blocks(
             cfg.hot_capacity + cfg.warm_capacity, m, base_pol.bn,
             base_pol.bm, interpret, base_pol.autotune)

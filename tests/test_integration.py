@@ -118,7 +118,8 @@ class TestCheckpointLineage:
         mgr = CheckpointManager(str(tmp_path), run_id="t0")
         rt = ClockRuntime(ck)
         mgr.save(1, state, rt.snapshot(), block=True)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         shardings = jax.tree.map(
             lambda _: NamedSharding(mesh, P()), state)
         restored, _ = mgr.restore(target_structure=state, shardings=shardings)

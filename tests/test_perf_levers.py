@@ -95,7 +95,8 @@ from repro.models.params import init_params
 from repro.models import transformer as T
 from repro.sharding import use_mesh_rules, make_rules
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = dataclasses.replace(get_smoke_config("grok_1_314b"), dtype="float32",
                           capacity_factor=64.0)
 params = init_params(jax.random.PRNGKey(0), cfg)

@@ -585,9 +585,9 @@ def test_generator_refuses_malformed_specs(bad):
 
 
 def test_generator_refuses_vmem_over_budget():
-    # fine structurally, but the int16 difference alone is ~1 GiB: over
-    # budget on EVERY backend
-    spec = CompareSpec(topology="rect", bi=1024, bj=1024, bm=512)
+    # fine structurally, but one 8-row chunk's int32 difference alone is
+    # 1 GiB: over budget on EVERY backend
+    spec = CompareSpec(topology="rect", bi=8192, bj=8192, bm=4096)
     assert vmem_estimate(spec) > VMEM_BUDGET["interpret"]
     with pytest.raises(ValueError, match="VMEM estimate"):
         validate(spec, "interpret")
@@ -601,7 +601,7 @@ def test_generator_refuses_vmem_over_budget():
 
 def test_vmem_estimate_orders_backends_and_depths():
     small = CompareSpec(topology="rect", bi=8, bj=8, bm=128)
-    big = CompareSpec(topology="rect", bi=256, bj=256, bm=512)
+    big = CompareSpec(topology="rect", bi=256, bj=256, bm=2048)
     assert vmem_estimate(small) < vmem_estimate(big)
     deeper = CompareSpec(topology="rect", bi=8, bj=8, bm=128,
                          pipeline_depth=3)

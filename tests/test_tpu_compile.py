@@ -1,0 +1,121 @@
+"""Compile the main-path Pallas kernels for a TPU v5e that is described,
+not attached.
+
+Interpret-mode tests cannot see what the chip's compiler refuses: block
+shapes off the (8, 128) tiling, ops with no Mosaic lowering, bool
+relayouts, kernels over the VMEM limit.  Each case here lowers one
+kernel through the same ops-layer padding and block resolution the TPU
+dispatch uses (``interpret=False``), at real widths, and compiles it
+for one chip of a described ``v5e:2x2`` topology.  Nothing runs.
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU compiler library, and every
+test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import generate, ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        # a compile for a described chip is written to the persistent
+        # cache but cannot be read back without one: keep it off
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _one_vs_many(S, pack):
+    N, m = (1 << 20, 256) if pack == "u8" else (65536, 256)
+    bn, bm = ops._one_vs_many_blocks(N, m, None, None, False, use_table=False)
+    if pack == "u8":
+        return (lambda q, p, b: ops._one_vs_many_body(q, p, b, bn, bm, m,
+                                                      False),
+                (S((m,), jnp.int32), S((N, m), jnp.uint8),
+                 S((N,), jnp.int32)))
+    return (lambda q, p: ops._classify_vs_many(q, p, bn=bn, bm=bm,
+                                               interpret=False),
+            (S((m,), jnp.int32), S((N, m), jnp.int32)))
+
+
+def _matrix(S, engine, n=1024, m=1024):
+    bi, bj, bm = ops._matrix_blocks(engine, n, n, m, None, None, None,
+                                    False, use_table=False)
+    u8 = (S((n, m), jnp.uint8), S((n,), jnp.int32))
+    if engine == "tri":
+        return (lambda c, b: ops._tri_flags(c, b, max(bi, bj), bm, m, True,
+                                            False), u8)
+    if engine == "full":
+        return (lambda r, b, c, cb: ops._full_rect_flags(
+            r, b, c, cb, bi, bj, bm, m, True, False), u8 + u8)
+    if engine == "mxu":
+        def mxu(r, b, c, cb):
+            rp, cp, bie, bje, bme = ops._rect_tiles(r, c, bi, bj, bm, False)
+            return generate.bloom_matrix_mxu_pallas(
+                rp, cp, ops._pad_base(b, rp.shape[0]),
+                ops._pad_base(cb, cp.shape[0]), n_thresholds=64, lo=0,
+                bi=bie, bj=bje, bm=bme, m_true=m)
+        return mxu, u8 + u8
+
+    def i32(r, c):
+        rp, cp, bie, bje, bme = ops._rect_tiles(r, c, bi, bj, bm, False)
+        cs = ops.pad_to(jnp.sum(c, axis=1).astype(jnp.float32)[None, :],
+                        cp.shape[0], axis=1)
+        return generate.bloom_matrix_pallas(rp, cp, cs, bi=bie, bj=bje,
+                                            bm=bme, m_true=m)
+    return i32, (S((n, m), jnp.int32), S((n, m), jnp.int32))
+
+
+def _hybrid(S, H=4096, T=65536, m=512):
+    def fn(q, meta, hot_sums, tail, base):
+        return ops._classify_hybrid(q, 7, meta, hot_sums, tail, base,
+                                    interpret=False, use_autotune=False)
+    return fn, (S((m,), jnp.int32), S((H, 2), jnp.int32),
+                S((H,), jnp.float32), S((T, m), jnp.uint8),
+                S((T,), jnp.int32))
+
+
+CASES = {
+    "one_vs_many_u8": lambda S: _one_vs_many(S, "u8"),
+    "one_vs_many_i32": lambda S: _one_vs_many(S, "i32"),
+    "tri_u8": lambda S: _matrix(S, "tri"),
+    "rect_u8": lambda S: _matrix(S, "full"),
+    "rect_i32_stats": lambda S: _matrix(S, "i32"),
+    "mxu": lambda S: _matrix(S, "mxu"),
+    "hybrid": _hybrid,
+    "merge_compare": lambda S: (
+        lambda a, b: ops.merge_compare(a, b, interpret=False),
+        (S((1024, 1024), jnp.int32), S((1024, 1024), jnp.int32))),
+    "tick": lambda S: (
+        lambda c, hi, lo: ops.tick(c, hi, lo, k=4, interpret=False),
+        (S((1024, 1024), jnp.int32), S((1024, 16), jnp.uint32),
+         S((1024, 16), jnp.uint32))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = CASES[case](S)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
