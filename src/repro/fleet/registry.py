@@ -560,10 +560,26 @@ class ClockRegistry:
         one-vs-many kernel (shard_map'd over the row shards when the
         policy carries a mesh) and overlays promoted rows through the
         exact int32 kernel — the bulk never drops to the fallback.
+
+        Spans: ``registry.classify_all`` around the call, and inside it
+        ``causal.classify`` (host dispatch), ``registry.readback`` (the
+        wait for the device and the transfer) and ``registry.fold``
+        (flags to status codes on the host); the counter
+        ``registry_readback_bytes`` sums the bytes read back.
         """
-        res = jax.device_get(          # single host transfer for the pytree
-            self.engine.classify(local, self._slab()))
-        return view_from_classify(res, self._alive_host, self.capacity)
+        obs = self.obs
+        with obs.trace.span("registry.classify_all",
+                            n=self.capacity) as span:
+            res = self.engine.classify(local, self._slab())
+            span.set(engine=res.engine)
+            with obs.trace.span("registry.readback"):
+                res = jax.device_get(res)  # one host transfer, every leaf
+            if obs.metrics:
+                obs.metrics.counter("registry_readback_bytes").inc(
+                    sum(x.nbytes for x in jax.tree.leaves(res)))
+            with obs.trace.span("registry.fold"):
+                return view_from_classify(res, self._alive_host,
+                                          self.capacity)
 
     def all_pairs(self, **kw):
         """Tiled all-pairs compare -> ``causal.ComparisonMatrix`` (also

@@ -76,6 +76,7 @@ def bloom_merge_compare_pallas(
             jax.ShapeDtypeStruct((B, 2), jnp.float32),
         ],
         interpret=interpret,
+        name="bloom_merge_compare",
     )(a, b)
     # fp[:, 0] = P(A ⊆ B by chance), fp[:, 1] = P(B ⊆ A)
     return merged, flags, sums, _eq3_pairs(sums, m_true if m_true else m)

@@ -69,4 +69,5 @@ def bloom_tick_pallas(
         out_specs=pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, m), cells.dtype),
         interpret=interpret,
+        name="bloom_tick",
     )(probes, cells)

@@ -115,6 +115,12 @@ class CompareSpec:
         return "/".join(parts)
 
 
+def kernel_name(spec: CompareSpec) -> str:
+    """The ``pallas_call`` name of ``spec``'s kernel: its HLO operation
+    and device-trace event carry it, the same for every block shape."""
+    return f"bloom_{spec.topology}_{spec.pack}"
+
+
 def validate(spec: CompareSpec, backend: str | None = None) -> None:
     """Refuse malformed or over-budget specs (raises ValueError)."""
     if spec.topology not in TOPOLOGIES:
@@ -417,6 +423,7 @@ def _emit_tri(spec: CompareSpec):
                 jax.ShapeDtypeStruct((N, N), acc),
             ],
             interpret=interpret,
+            name=kernel_name(spec),
             **_compiler_params(spec, 2, interpret),
         )(ti, tj, *operands)
         return le, ge
@@ -466,6 +473,7 @@ def _emit_rect_u8(spec: CompareSpec):
                 jax.ShapeDtypeStruct((N, M), acc),
             ],
             interpret=interpret,
+            name=kernel_name(spec),
             **_compiler_params(spec, 3, interpret),
         )(*operands)
         return le, ge
@@ -523,6 +531,7 @@ def _emit_rect_i32_stats(spec: CompareSpec):
                 jax.ShapeDtypeStruct((N, 1), jnp.float32),
             ],
             interpret=interpret,
+            name=kernel_name(spec),
             **_compiler_params(spec, 3, interpret),
         )(rows, cols)
         fp = eq3_fp(row_sums, col_sums, m_true if m_true else m)
@@ -596,6 +605,7 @@ def _emit_mxu(spec: CompareSpec):
             out_specs=pl.BlockSpec((bi, bj), lambda i, j, jm: (i, j)),
             out_shape=jax.ShapeDtypeStruct((N, M), jnp.float32),
             interpret=interpret,
+            name=kernel_name(spec),
             **_compiler_params(spec, 3, interpret),
         )(rows, cols, row_base, col_base)
         return viol
@@ -653,6 +663,7 @@ def _emit_one_vs_many(spec: CompareSpec):
                 jax.ShapeDtypeStruct((N, 2), jnp.float32),
             ],
             interpret=interpret,
+            name=kernel_name(spec),
             **_compiler_params(spec, 2, interpret),
         )(*operands)
         return flags, sums, _eq3_pairs(sums, m_true)
@@ -748,6 +759,7 @@ def _emit_hybrid(spec: CompareSpec):
                 jax.ShapeDtypeStruct((H + T, 2), jnp.float32),
             ],
             interpret=interpret,
+            name=kernel_name(spec),
             **_compiler_params(spec, 2, interpret),
         )(v_local, q, hot_meta, hot_sums, tail, tail_base)
         hot = jnp.arange(H + T)[:, None] < H

@@ -12,6 +12,12 @@ monotonic within a process, immune to wall-clock steps.  A ``meta``
 header line records the wall-clock origin so multi-process traces can
 be aligned after the fact.
 
+Every span of an enabled tracer is also a ``jax.profiler.TraceAnnotation``
+of the same name, so while a JAX profiler session runs the program's
+spans land in its ``.xplane.pb`` beside the device operations, on the
+device trace's clock.  With no session running the annotation costs one
+activity check.
+
 Attributes are *typed*: ``str``/``int``/``float``/``bool``/``None``
 pass through verbatim; anything else is stringified at emit time so a
 stray jax array in an attr can never make a record unserializable.
@@ -44,7 +50,8 @@ def _typed(attrs: dict) -> dict:
 class _Span:
     """One live span: its own context manager, re-entrant never."""
 
-    __slots__ = ("_tracer", "name", "sid", "parent", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "sid", "parent", "attrs", "_t0",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -53,6 +60,7 @@ class _Span:
         self.sid = next(tracer._ids)
         self.parent = None
         self._t0 = 0
+        self._annotation = tracer._annotate(name)
 
     def set(self, **attrs) -> "_Span":
         """Attach attributes discovered mid-span (engine chosen, bytes
@@ -61,6 +69,7 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
         stack = self._tracer._stack()
         self.parent = stack[-1].sid if stack else None
         stack.append(self)
@@ -71,6 +80,7 @@ class _Span:
         t1 = time.perf_counter_ns()
         self._tracer._stack().pop()
         self._tracer._emit(self, t1)
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -85,6 +95,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._origin_ns = time.perf_counter_ns()
         self.origin_unix = time.time()
+        # imported here, not at module level: repro.obs stays light
+        from jax.profiler import TraceAnnotation
+        self._annotate = TraceAnnotation
         self._file = None
         if self._path:
             self._file = open(self._path, "w")

@@ -482,7 +482,9 @@ class TieredRegistry:
             base_host=self._w_base, wide=self._w_wide)
         res = jax.device_get(self.engine.classify(
             query, slab, bn=self.blocks[0], bm=self.blocks[1]))
-        return view_from_classify(res, self._w_alive, self.cfg.warm_capacity)
+        with self.obs.trace.span("registry.fold"):
+            return view_from_classify(res, self._w_alive,
+                                      self.cfg.warm_capacity)
 
     def _classify_cold(self, query, sids, pos, status, fp, sums) -> str:
         """Chunked classify over decoded cold frames: each chunk builds
@@ -514,7 +516,8 @@ class TieredRegistry:
                 query, slab, bn=self.blocks[0], bm=self.blocks[1]))
             alive = np.zeros(B, bool)
             alive[:len(chunk)] = True
-            view = view_from_classify(res, alive, B)
+            with self.obs.trace.span("registry.fold"):
+                view = view_from_classify(res, alive, B)
             engine = view.engine
             for i, sid in enumerate(chunk):
                 j = pos[sid]

@@ -121,6 +121,54 @@ def test_tracer_threads_get_independent_stacks():
     assert by_name["worker"]["parent"] is None
 
 
+def _host_events(trace_dir) -> dict:
+    """{name: [(start_ns, end_ns)]} of the host events of the one
+    ``.xplane.pb`` profile written under ``trace_dir``."""
+    import glob
+
+    import jax
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out: dict = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_enabled_spans_land_in_the_profiler_trace(tmp_path):
+    """An enabled tracer's span is also a host event of a running JAX
+    profiler trace, inside the annotation around it; a null tracer's is
+    not, and the JSONL record is what it was."""
+    import jax
+    from repro.obs import NULL_TRACER
+    tr = Tracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            with tr.span("registry.fold", n=3):
+                jnp.arange(8).sum().block_until_ready()
+            with NULL_TRACER.span("registry.null_span"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    (window,), (span,) = events["bench.window"], events["registry.fold"]
+    assert window[0] <= span[0] < span[1] <= window[1]
+    assert "registry.null_span" not in events
+    (rec,) = tr.events()
+    assert set(rec) == {"name", "sid", "parent", "ts_us", "dur_us", "pid",
+                        "tid", "attrs"}
+    assert rec["name"] == "registry.fold" and rec["attrs"] == {"n": 3}
+
+
 def test_load_spans_rejects_garbage(tmp_path):
     bad = tmp_path / "bad.jsonl"
     bad.write_text('{"name": "x"}\n')      # missing sid/ts_us/dur_us
@@ -290,6 +338,71 @@ def test_policy_label_excludes_observer():
     riding = CausalPolicy(fp_threshold=1.0, observer=Observer())
     assert plain.label() == riding.label()
     hash(riding)                           # observer keeps policy hashable
+
+
+@pytest.mark.parametrize("wide", [False, True])
+def test_classify_all_spans_and_readback_bytes(wide):
+    """One sweep: a ``registry.classify_all`` root whose children are the
+    host dispatch, the readback and the host fold, in that order; the
+    readback counter sums the bytes of the leaves read back, 14 a row
+    and the query's sum.  Without an observer the view is the same."""
+    capacity = 64
+    peers = _fleet(40, seed=3)
+    if wide:                                   # a row on the int32 rim
+        peers["peer0"] = _clock(np.arange(M) * 4)    # spans past a byte
+    obs = Observer(trace=Tracer(), metrics=MetricsRecorder())
+    registry = ClockRegistry(capacity=capacity, m=M, k=K,
+                             policy=CausalPolicy(observer=obs))
+    registry.admit_many(peers)
+    local = _dominating(_fleet(40, seed=3))
+    view = registry.classify_all(local)
+    evs = [e for e in obs.trace.events() if e["name"] != "registry.admit"]
+    assert [e["name"] for e in evs] == [
+        "causal.classify", "registry.readback", "registry.fold",
+        "registry.classify_all"]
+    *children, root = evs
+    assert root["parent"] is None
+    assert root["attrs"] == {"n": capacity, "engine": view.engine}
+    assert ("wide_overlay" in view.engine) == wide
+    assert all(c["parent"] == root["sid"] for c in children)
+    for a, b in zip(children, children[1:]):
+        assert a["ts_us"] + a["dur_us"] <= b["ts_us"]
+    counter = obs.metrics.counter("registry_readback_bytes")
+    assert counter.value == 14 * capacity + 4
+    registry.classify_all(local)
+    assert counter.value == 2 * (14 * capacity + 4)
+
+    plain = ClockRegistry(capacity=capacity, m=M, k=K)
+    plain.admit_many(peers)
+    assert not plain.obs
+    ref = plain.classify_all(local)
+    np.testing.assert_array_equal(ref.status, view.status)
+    np.testing.assert_array_equal(ref.fp, view.fp)
+
+
+def test_tiered_folds_share_the_registry_fold_span():
+    """The tiered registry's warm and cold folds are ``registry.fold``
+    spans too, under ``tiers.classify``."""
+    from repro.serve.tiers import TierConfig, TieredRegistry
+    obs = Observer(trace=Tracer(), metrics=MetricsRecorder())
+    tiers = TieredRegistry(
+        TierConfig(hot_capacity=6, warm_capacity=10, promote_after=2,
+                   demote_batch=2, spill_batch=4, cold_batch=4),
+        m=M, k=K, policy=CausalPolicy(observer=obs))
+    clocks = _fleet(30, seed=4)
+    tiers.admit_many(clocks)
+    assert set(tiers._tier_of.values()) == {"hot", "warm", "cold"}
+    tiers.classify(_dominating(clocks))
+    tiers.close()
+    evs = obs.trace.events()
+    (top,) = [e for e in evs if e["name"] == "tiers.classify"]
+    folds = [e for e in evs if e["name"] == "registry.fold"]
+    sweeps = {e["sid"] for e in evs if e["name"] == "registry.classify_all"}
+    # the hot slab's own fold, then warm's, then one per cold chunk
+    assert len(folds) == 2 + math.ceil(
+        sum(t == "cold" for t in tiers._tier_of.values()) / 4)
+    assert sum(f["parent"] in sweeps for f in folds) == 1
+    assert all(f["parent"] in sweeps | {top["sid"]} for f in folds)
 
 
 def test_session_spans_metrics_and_audit_loopback():

@@ -13,6 +13,10 @@ only one process at a time may load the TPU compiler library, and every
 test worker imports this file.
 """
 import os
+import re
+import sys
+import types
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +24,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import generate, ops
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench.kernels import ovm  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -111,11 +118,45 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_compiles_for_v5e(one_chip, case):
+#: the stable ``pallas_call`` name that each case's kernel gives its
+#: HLO operation, and so its event in the device trace
+NAMES = {
+    "one_vs_many_u8": "bloom_one_vs_many_u8",
+    "one_vs_many_i32": "bloom_one_vs_many_i32",
+    "tri_u8": "bloom_tri_u8",
+    "rect_u8": "bloom_rect_u8",
+    "rect_i32_stats": "bloom_rect_i32",
+    "mxu": "bloom_mxu_u8",
+    "hybrid": "bloom_hybrid_u8",
+    "merge_compare": "bloom_merge_compare",
+    "tick": "bloom_tick",
+}
+
+
+def _kernel_calls(one_chip, case) -> list:
+    """The HLO lines of the Pallas calls the case compiles to."""
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, args = CASES[case](S)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return [line for line in compiled.as_text().splitlines()
+            if "tpu_custom_call" in line]
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    calls = _kernel_calls(one_chip, case)
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{NAMES[case]}(\.\d+)? = ", line), line
+
+
+@pytest.mark.parametrize("case", ["one_vs_many_u8", "one_vs_many_i32"])
+def test_ovm_trace_rule_matches_the_packed_kernel_only(one_chip, case):
+    """``bench/kernels/ovm.py`` picks the packed one-vs-many kernel out of
+    a device trace by its operation's text; the int32 kernel, which runs
+    the rim, must not match."""
+    (line,) = _kernel_calls(one_chip, case)
+    assert ovm.is_kernel(types.SimpleNamespace(name=line)) == (
+        case == "one_vs_many_u8")
