@@ -170,35 +170,76 @@ class FleetView:
         return self.fp <= threshold
 
 
+def _fold(xp, q_le_p, p_le_q, fp_q_before_p, fp_p_before_q, alive):
+    """Classify flags -> (status int8, claimed-direction fp float32).
+
+    One elementwise select chain for numpy (``xp=np``) and ``jnp``
+    alike: FORKED by default, ANCESTOR where the peer ≼ local,
+    DESCENDANT where local ≼ peer overrides it, SAME where both hold,
+    DEAD where the slot is not alive.  fp is the claimed direction's
+    Eq. 3 value as ``ClassifyResult.claimed_fp`` selects it; SAME,
+    FORKED and DEAD are exact and carry 0.  No arithmetic: every output
+    bit is an input bit."""
+    status = xp.where(p_le_q, ANCESTOR, FORKED)
+    status = xp.where(q_le_p, DESCENDANT, status)
+    status = xp.where(q_le_p & p_le_q, SAME, status)
+    status = xp.where(alive, status, DEAD).astype(np.int8)
+    fp = xp.where(p_le_q, fp_p_before_q, fp_q_before_p)
+    fp = xp.where(alive & (q_le_p != p_le_q), fp, 0.0).astype(np.float32)
+    return status, fp
+
+
+@jax.jit
+def _fold_on_device(q_le_p, p_le_q, fp_q_before_p, fp_p_before_q, alive):
+    return _fold(jnp, q_le_p, p_le_q, fp_q_before_p, fp_p_before_q, alive)
+
+
 def view_from_classify(res, alive: np.ndarray, capacity: int,
-                       local_sum: float | None = None) -> FleetView:
-    """Fold a host-side ``ClassifyResult`` into a ``FleetView``.
+                       local_sum: float | None = None, *,
+                       alive_dev: jax.Array | None = None,
+                       obs=None) -> FleetView:
+    """Fold a ``ClassifyResult`` into a host-side ``FleetView``.
 
     The ONE place classify flags become status codes + claimed-direction
     fp — ``ClockRegistry.classify_all`` and the tiered registry
     (``repro.serve.tiers``) both route through it, so a tier split can
     never drift from the flat slab's verdict semantics.
+
+    Where the fold runs follows from where ``res`` lives: a device
+    result is folded on the device (``alive_dev``, or ``alive``
+    uploaded) and only status, fp and the sums are read back — 9 bytes
+    a row plus the query's sum; a host result (numpy leaves) is folded
+    in numpy.  ``capacity`` is the view's length, the result's row
+    count.  Span ``registry.fold`` covers the fold, with
+    ``registry.readback`` inside it around the transfer; counters
+    ``registry_fold{where=device|host}`` and ``registry_readback_bytes``.
     """
+    obs = resolve(obs)
     alive = np.asarray(alive, bool)
-    p_le_q = res.after()           # peer ≼ local
-    q_le_p = res.before()          # local ≼ peer
-    equal = res.equal()
-    status = np.full(capacity, FORKED, np.int8)
-    status[p_le_q] = ANCESTOR
-    status[q_le_p] = DESCENDANT
-    status[equal] = SAME
-    status[~alive] = DEAD
-    # fp of the direction actually claimed; SAME and FORKED are exact
-    fp = np.asarray(res.claimed_fp(), np.float32)
-    fp[~alive] = 0.0
-    return FleetView(
-        status=status,
-        fp=fp,
-        sums=res.sum_p,
-        alive=alive.copy(),
-        local_sum=float(res.sum_q) if local_sum is None else local_sum,
-        engine=res.engine or "",
-    )
+    flags = (res.q_le_p, res.p_le_q, res.fp_q_before_p, res.fp_p_before_q)
+    where = "device" if isinstance(res.q_le_p, jax.Array) else "host"
+    with obs.trace.span("registry.fold"):
+        if where == "device":
+            folded = _fold_on_device(
+                *flags, alive if alive_dev is None else alive_dev)
+            with obs.trace.span("registry.readback"):
+                host = jax.device_get((*folded, res.sum_p, res.sum_q))
+            if obs.metrics:
+                obs.metrics.counter("registry_readback_bytes").inc(
+                    sum(x.nbytes for x in host))
+            status, fp, sums, sum_q = host
+        else:
+            status, fp = _fold(np, *flags, alive)
+            sums, sum_q = res.sum_p, res.sum_q
+        obs.metrics.counter("registry_fold", where=where).inc()
+        return FleetView(
+            status=status,
+            fp=fp,
+            sums=sums,
+            alive=alive.copy(),
+            local_sum=float(sum_q) if local_sum is None else local_sum,
+            engine=res.engine or "",
+        )
 
 
 @jax.jit
@@ -562,24 +603,19 @@ class ClockRegistry:
         exact int32 kernel — the bulk never drops to the fallback.
 
         Spans: ``registry.classify_all`` around the call, and inside it
-        ``causal.classify`` (host dispatch), ``registry.readback`` (the
-        wait for the device and the transfer) and ``registry.fold``
-        (flags to status codes on the host); the counter
-        ``registry_readback_bytes`` sums the bytes read back.
+        ``causal.classify`` (host dispatch) and ``registry.fold`` (the
+        fold's dispatch on the device and the host-side wrap), which
+        holds ``registry.readback`` (the wait for the device and the
+        transfer of status, fp and sums: 9 bytes a row plus 4); the
+        counter ``registry_readback_bytes`` sums the bytes read back.
         """
         obs = self.obs
         with obs.trace.span("registry.classify_all",
                             n=self.capacity) as span:
             res = self.engine.classify(local, self._slab())
             span.set(engine=res.engine)
-            with obs.trace.span("registry.readback"):
-                res = jax.device_get(res)  # one host transfer, every leaf
-            if obs.metrics:
-                obs.metrics.counter("registry_readback_bytes").inc(
-                    sum(x.nbytes for x in jax.tree.leaves(res)))
-            with obs.trace.span("registry.fold"):
-                return view_from_classify(res, self._alive_host,
-                                          self.capacity)
+            return view_from_classify(res, self._alive_host, self.capacity,
+                                      alive_dev=self.alive, obs=obs)
 
     def all_pairs(self, **kw):
         """Tiled all-pairs compare -> ``causal.ComparisonMatrix`` (also

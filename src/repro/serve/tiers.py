@@ -36,7 +36,6 @@ import os
 import tempfile
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -480,11 +479,10 @@ class TieredRegistry:
             jnp.asarray(self._w_u8),
             jnp.asarray(_fold_i32(self._w_base)),
             base_host=self._w_base, wide=self._w_wide)
-        res = jax.device_get(self.engine.classify(
-            query, slab, bn=self.blocks[0], bm=self.blocks[1]))
-        with self.obs.trace.span("registry.fold"):
-            return view_from_classify(res, self._w_alive,
-                                      self.cfg.warm_capacity)
+        res = self.engine.classify(
+            query, slab, bn=self.blocks[0], bm=self.blocks[1])
+        return view_from_classify(res, self._w_alive,
+                                  self.cfg.warm_capacity, obs=self.obs)
 
     def _classify_cold(self, query, sids, pos, status, fp, sums) -> str:
         """Chunked classify over decoded cold frames: each chunk builds
@@ -512,12 +510,11 @@ class TieredRegistry:
                         cells.astype(np.int64) + int(snap["base"]))
             slab = PackedSlab(jnp.asarray(u8), jnp.asarray(_fold_i32(base)),
                               base_host=base, wide=wide)
-            res = jax.device_get(self.engine.classify(
-                query, slab, bn=self.blocks[0], bm=self.blocks[1]))
+            res = self.engine.classify(
+                query, slab, bn=self.blocks[0], bm=self.blocks[1])
             alive = np.zeros(B, bool)
             alive[:len(chunk)] = True
-            with self.obs.trace.span("registry.fold"):
-                view = view_from_classify(res, alive, B)
+            view = view_from_classify(res, alive, B, obs=self.obs)
             engine = view.engine
             for i, sid in enumerate(chunk):
                 j = pos[sid]

@@ -343,9 +343,10 @@ def test_policy_label_excludes_observer():
 @pytest.mark.parametrize("wide", [False, True])
 def test_classify_all_spans_and_readback_bytes(wide):
     """One sweep: a ``registry.classify_all`` root whose children are the
-    host dispatch, the readback and the host fold, in that order; the
-    readback counter sums the bytes of the leaves read back, 14 a row
-    and the query's sum.  Without an observer the view is the same."""
+    host dispatch and then the fold, which holds the readback; the
+    readback counter sums the bytes read back, 9 a row (status, fp,
+    sum) and the query's sum, and the fold counts as a device fold.
+    Without an observer the view is the same."""
     capacity = 64
     peers = _fleet(40, seed=3)
     if wide:                                   # a row on the int32 rim
@@ -360,17 +361,24 @@ def test_classify_all_spans_and_readback_bytes(wide):
     assert [e["name"] for e in evs] == [
         "causal.classify", "registry.readback", "registry.fold",
         "registry.classify_all"]
-    *children, root = evs
+    dispatch, readback, fold, root = evs
     assert root["parent"] is None
     assert root["attrs"] == {"n": capacity, "engine": view.engine}
     assert ("wide_overlay" in view.engine) == wide
-    assert all(c["parent"] == root["sid"] for c in children)
-    for a, b in zip(children, children[1:]):
-        assert a["ts_us"] + a["dur_us"] <= b["ts_us"]
+    assert dispatch["parent"] == fold["parent"] == root["sid"]
+    assert readback["parent"] == fold["sid"]
+    assert dispatch["ts_us"] + dispatch["dur_us"] <= fold["ts_us"]
+    assert fold["ts_us"] <= readback["ts_us"]
+    assert (readback["ts_us"] + readback["dur_us"]
+            <= fold["ts_us"] + fold["dur_us"])
     counter = obs.metrics.counter("registry_readback_bytes")
-    assert counter.value == 14 * capacity + 4
+    folds = obs.metrics.counter("registry_fold", where="device")
+    assert counter.value == 9 * capacity + 4
+    assert folds.value == 1
     registry.classify_all(local)
-    assert counter.value == 2 * (14 * capacity + 4)
+    assert counter.value == 2 * (9 * capacity + 4)
+    assert folds.value == 2
+    assert obs.metrics.counter("registry_fold", where="host").value == 0
 
     plain = ClockRegistry(capacity=capacity, m=M, k=K)
     plain.admit_many(peers)

@@ -17,7 +17,8 @@ import pytest
 from repro.causal import CausalPolicy
 from repro.core import clock as bc
 from repro.core.sim import SimConfig, run_gossip_sim
-from repro.fleet import ClockRegistry, GossipConfig, fleet_health, gossip_round
+from repro.fleet import (DEAD, ClockRegistry, GossipConfig, fleet_health,
+                         gossip_round)
 from repro.launch.mesh import make_fleet_mesh
 from repro.runtime.clock_runtime import ClockConfig, ClockRuntime
 
@@ -82,6 +83,29 @@ def test_classify_all_shard_invariance(host_devices, seed):
         assert reg.n_shards == shards
         _evict_some(reg, seed)
         _assert_views_identical(reg.classify_all(local), ref)
+
+
+@pytest.mark.parametrize("shards", SHARD_COUNTS)
+def test_classify_all_matches_mask_fold_sharded(host_devices, shards,
+                                                mask_fold,
+                                                views_bit_identical):
+    """The device fold runs elementwise on the row-sharded result: the
+    view equals the boolean-mask fold of the same result read back, with
+    dead slots and an int32-rim row."""
+    peers = _random_fleet(21)
+    wide = np.zeros(M, np.int64)
+    wide[::5] = 700                         # span 700 >> U8_MAX
+    peers["peer4"] = _clock(wide)
+    local = bc.merge(peers["peer0"], peers["peer3"])
+    reg = _filled(peers, mesh=make_fleet_mesh(shards))
+    _evict_some(reg, 21)
+    assert not reg.packed
+    got = reg.classify_all(local)
+    want = mask_fold(jax.device_get(reg.engine.classify(local, reg._slab())),
+                     reg._alive_host, CAP)
+    assert "wide_overlay" in got.engine
+    assert (got.status == DEAD).sum() == 5
+    views_bit_identical(got, want)
 
 
 @pytest.mark.parametrize("seed", range(4))
