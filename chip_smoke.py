@@ -301,8 +301,7 @@ def phase_hybrid() -> dict:
     vs = rng.integers(0, V + 1, n)
     # Every verdict occurs in both the hot and the tail part: a session
     # at version v has seen the local chain's first v events, and a
-    # private one also holds two events of its own.  Private events are
-    # hashed one by one on admission, so keep them to a few hundred.
+    # private one also holds two events of its own.
     perm = rng.permutation(n)
     private = np.zeros(n, bool)
     for part in (perm[:H], perm[H:]):

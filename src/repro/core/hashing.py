@@ -15,6 +15,11 @@ indices per event.  We follow standard bloom-filter engineering practice:
 Everything is pure jnp on uint32 pairs so it runs identically on
 TPU (which has no native 64-bit multiply in the VPU fast path) and CPU.
 We represent a 64-bit value as (hi, lo) uint32 lanes.
+
+``bloom_indices_host`` is the same double hash in vectorised numpy
+uint64 arithmetic, bit for bit: host code that hashes many events at
+once (bulk admission, a local chain) uses it instead of paying the
+eager jnp ops per event.
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ __all__ = [
     "splitmix64",
     "murmur64",
     "bloom_indices",
+    "bloom_indices_host",
     "stable_event_id",
 ]
 
@@ -125,6 +131,39 @@ def bloom_indices(event_hi, event_lo, k: int, m: int):
     i = jnp.arange(k, dtype=jnp.uint32)
     idx = h1[..., None] + i * h2[..., None]
     return (idx % jnp.uint32(m)).astype(jnp.uint32)
+
+
+def _splitmix64_host(x: np.ndarray) -> np.ndarray:
+    x = x + np.uint64(0x9E3779B97F4A7C15)
+    x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return x ^ (x >> np.uint64(31))
+
+
+def _murmur64_host(x: np.ndarray) -> np.ndarray:
+    x = (x ^ (x >> np.uint64(33))) * np.uint64(0xFF51AFD7ED558CCD)
+    x = (x ^ (x >> np.uint64(33))) * np.uint64(0xC4CEB9FE1A85EC53)
+    return x ^ (x >> np.uint64(33))
+
+
+def _fold32(x: np.ndarray) -> np.ndarray:
+    return ((x >> np.uint64(32)) ^ (x & np.uint64(0xFFFFFFFF))).astype(
+        np.uint32)
+
+
+def bloom_indices_host(event_hi, event_lo, k: int, m: int) -> np.ndarray:
+    """``bloom_indices`` on the host: numpy uint64 arithmetic wraps mod
+    2^64 exactly as the (hi, lo) lanes do, so the indices are equal bit
+    for bit.  Returns a uint32 array of shape S + (k,)."""
+    hi = np.asarray(event_hi, np.uint32)
+    lo = np.asarray(event_lo, np.uint32)
+    x = np.atleast_1d((hi.astype(np.uint64) << np.uint64(32))
+                      | lo.astype(np.uint64))
+    h1 = _fold32(_splitmix64_host(x)).astype(np.uint64)
+    h2 = (_fold32(_murmur64_host(x)) | np.uint32(1)).astype(np.uint64)
+    i = np.arange(k, dtype=np.uint64)
+    idx = (h1[..., None] + i * h2[..., None]) & np.uint64(0xFFFFFFFF)
+    return (idx % np.uint64(m)).astype(np.uint32).reshape(hi.shape + (k,))
 
 
 def stable_event_id(*parts) -> tuple[int, int]:

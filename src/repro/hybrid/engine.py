@@ -42,6 +42,7 @@ import dataclasses
 import json
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,11 +51,15 @@ from repro.causal.policy import CausalPolicy
 from repro.causal.results import ClassifyResult
 from repro.core import clock as bc
 from repro.core import wire
-from repro.core.hashing import bloom_indices
+from repro.core.hashing import bloom_indices_host, stable_event_id
 from repro.obs.audit import NULL_AUDIT
 from repro.obs.observer import resolve
 
 __all__ = ["HybridConfig", "HybridEngine", "HybridSlab", "HybridView"]
+
+#: logical cells minted per chunk of rows (int32), so a bulk admission
+#: holds at most 64 MiB of them at once whatever its size
+_MINT_CELLS = 1 << 24
 
 
 @dataclasses.dataclass
@@ -136,7 +141,7 @@ class HybridView:
         return 0.0
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class _Session:
     """Catalog entry: the exact description every representation of the
     session is derived from."""
@@ -163,6 +168,8 @@ class HybridEngine:
         self.m = cfg.m
         self.k = cfg.k
         pol = policy or CausalPolicy(interpret=cfg.interpret)
+        if observer is not None and pol.observer is None:
+            pol = dataclasses.replace(pol, observer=observer)
         self.engine = CausalEngine(pol)
         self.obs = resolve(observer)
         self.audit = audit if audit is not None else NULL_AUDIT
@@ -170,6 +177,9 @@ class HybridEngine:
         # are stored mod the CURRENT m and fold exactly on resize.
         self._probes = np.zeros((0, cfg.k), np.int64)
         self._local_cells = np.zeros(cfg.m, np.int64)
+        # [V'+1, m] int32 cumulative cells of the chain's first V' events
+        # (row v mints a session's prefix), extended lazily
+        self._prefix = np.zeros((1, cfg.m), np.int32)
         self.sessions: dict = {}
         # hot set: insertion-ordered sid -> _Session (values alias
         # ``sessions``; the dict itself is the device row order)
@@ -193,6 +203,7 @@ class HybridEngine:
         self.promotions = 0
         self.demotions = 0
         self.resizes = 0
+        self.mirror_rebuilds = 0
         self.adaptive = None
         if cfg.fp_budget is not None:
             from repro.hybrid.adaptive import AdaptiveConfig, AdaptivePolicy
@@ -209,16 +220,19 @@ class HybridEngine:
     def append_local(self, event_hi: int, event_lo: int) -> None:
         """Record one local event: extends the chain every hot verdict
         is a containment test against, and ticks the local clock."""
-        probes = self._probe_of(event_hi, event_lo)
-        self._probes = np.concatenate([self._probes, probes[None, :]])
-        np.add.at(self._local_cells, probes, 1)
+        self._append_local(np.asarray([[event_hi, event_lo]], np.int64))
 
     def advance_local(self, count: int = 1) -> None:
         """Append ``count`` fresh deterministic local events."""
-        from repro.core.hashing import stable_event_id
-        for _ in range(count):
-            hi, lo = stable_event_id(b"hybrid/local", self.local_version)
-            self.append_local(hi, lo)
+        V = self.local_version
+        self._append_local(np.asarray(
+            [stable_event_id(b"hybrid/local", V + i) for i in range(count)],
+            np.int64).reshape(-1, 2))
+
+    def _append_local(self, ids: np.ndarray) -> None:
+        probes = self._probe_of(ids[:, 0], ids[:, 1])
+        self._probes = np.concatenate([self._probes, probes])
+        np.add.at(self._local_cells, probes.ravel(), 1)
 
     def local_clock(self) -> bc.BloomClock:
         return bc.BloomClock(
@@ -226,8 +240,20 @@ class HybridEngine:
             base=jnp.zeros((), jnp.int32), k=self.k)
 
     def _probe_of(self, hi, lo) -> np.ndarray:
-        idx = bloom_indices(np.uint32(hi), np.uint32(lo), self.k, self.m)
-        return np.asarray(idx, np.int64)
+        """[..., k] int64 probes of event ids at the current geometry."""
+        return bloom_indices_host(hi, lo, self.k, self.m).astype(np.int64)
+
+    def _prefix_upto(self, v: int) -> np.ndarray:
+        """The prefix-cell table, extended to cover chain prefix ``v``."""
+        have = self._prefix.shape[0] - 1
+        if v > have:
+            new = np.zeros((v - have, self.m), np.int32)
+            rows = np.repeat(np.arange(v - have), self.k)
+            np.add.at(new, (rows, self._probes[have:v].ravel()), 1)
+            np.cumsum(new, axis=0, out=new)
+            new += self._prefix[-1]
+            self._prefix = np.concatenate([self._prefix, new])
+        return self._prefix
 
     # ------------------------------------------------------------------
     # admission / representation moves
@@ -236,16 +262,53 @@ class HybridEngine:
         """Register a session from its exact description: a ``v``-long
         prefix of the local chain plus private event ids.  Lands in the
         tail representation; access counters promote it later."""
-        if v > self.local_version:
+        ids = np.asarray(list(events), np.int64).reshape(-1, 2)
+        self.admit_many([sid], [v], (np.asarray([0, len(ids)]), ids))
+
+    def admit_many(self, sids, v, events=None) -> None:
+        """Register many sessions at once, as a loop of ``admit`` would
+        (same slots, same rows, same catalog order).
+
+        ``v``: int array of chain prefix lengths, one per sid.
+        ``events``: None (no private events) or ``(offsets, ids)``, where
+        session ``i``'s private event ids are ``ids[offsets[i]:
+        offsets[i+1]]`` and ``ids`` is ``[E, 2]`` ``(hi, lo)``.  A sid
+        already present is replaced; a sid may appear once per call."""
+        sids = list(sids)
+        n = len(sids)
+        v = np.asarray(v, np.int64).reshape(-1)
+        if v.shape[0] != n:
+            raise ValueError(f"{n} sids but {v.shape[0]} versions")
+        if events is None:
+            offsets, ids = np.zeros(n + 1, np.int64), np.zeros((0, 2))
+        else:
+            offsets = np.asarray(events[0], np.int64)
+            ids = np.asarray(events[1]).reshape(-1, 2)
+        if (offsets.shape != (n + 1,) or offsets[0] != 0
+                or offsets[-1] != len(ids) or (np.diff(offsets) < 0).any()):
+            raise ValueError("events offsets must run 0..len(ids), "
+                             "non-decreasing, one more than sids")
+        if n and (v.min() < 0 or v.max() > self.local_version):
             raise ValueError(
-                f"session prefix v={v} exceeds local chain "
-                f"length {self.local_version}")
-        if sid in self.sessions:
-            self.release(sid)
-        s = _Session(v=int(v),
-                     events=tuple((int(h), int(l)) for h, l in events))
-        self.sessions[sid] = s
-        self._mint_into_tail(sid, s)
+                f"session prefix v in [{v.min()}, {v.max()}] exceeds local "
+                f"chain length {self.local_version}")
+        if len(set(sids)) != n:
+            raise ValueError("a sid appears twice in one admission")
+        with self.obs.trace.span("hybrid.admit_many", rows=n,
+                                 private_events=len(ids)):
+            for sid in sids:
+                if sid in self.sessions:
+                    self.release(sid)
+            if n > len(self._t_free):
+                raise RuntimeError("tail slab full; grow tail_capacity")
+            pairs = list(map(tuple, ids.astype(np.int64).tolist()))
+            at = offsets.tolist()
+            new = [_Session(v=vi, events=tuple(pairs[a:b]))
+                   for vi, a, b in zip(v.tolist(), at, at[1:])]
+            self.sessions.update(zip(sids, new))
+            self._place(new, v, offsets, ids)
+            if self.obs:
+                self.obs.metrics.counter("hybrid_admitted").inc(n)
 
     def release(self, sid) -> None:
         s = self.sessions.pop(sid, None)
@@ -256,35 +319,64 @@ class HybridEngine:
         elif s.slot is not None:
             self._free_slot(s)
 
-    def _mint_cells(self, s: _Session) -> np.ndarray:
-        """Deterministic logical cells of a session's bloom shadow at
-        the CURRENT geometry — a fold of any previous mint."""
-        cells = np.zeros(self.m, np.int64)
-        if s.v:
-            np.add.at(cells, self._probes[:s.v].ravel(), 1)
-        for hi, lo in s.events:
-            np.add.at(cells, self._probe_of(hi, lo), 1)
+    @staticmethod
+    def _describe(sessions: list):
+        """(v, offsets, ids) of catalog entries, as ``admit_many`` takes."""
+        v = np.asarray([s.v for s in sessions], np.int64)
+        counts = np.asarray([s.n_private for s in sessions], np.int64)
+        offsets = np.concatenate([[0], np.cumsum(counts)])
+        ids = np.asarray([e for s in sessions for e in s.events],
+                         np.int64).reshape(-1, 2)
+        return v, offsets, ids
+
+    def _mint(self, v: np.ndarray, offsets: np.ndarray,
+              ids: np.ndarray) -> np.ndarray:
+        """[n, m] int32 logical cells at the CURRENT geometry (a fold of
+        any previous mint): each row's chain prefix from the prefix-cell
+        table plus the probes of its private events."""
+        cells = self._prefix_upto(int(v.max(initial=0)))[v]
+        if len(ids):
+            rows = np.repeat(np.arange(len(v)), np.diff(offsets) * self.k)
+            probes = self._probe_of(ids[:, 0], ids[:, 1])
+            np.add.at(cells, (rows, probes.ravel()), 1)
         return cells
 
-    def _mint_into_tail(self, sid, s: _Session) -> None:
-        if not self._t_free:
+    def _mint_cells(self, s: _Session) -> np.ndarray:
+        """One session's logical cells (the one-row ``_mint``)."""
+        return self._mint(*self._describe([s]))[0].astype(np.int64)
+
+    def _place(self, sessions: list, v: np.ndarray, offsets: np.ndarray,
+               ids: np.ndarray) -> None:
+        """Mint catalog entries into free tail slots, in chunks: the
+        slots a loop of single admissions would pop, written the same
+        way (u8 residuals over a per-row base, or the exact int32 row on
+        the side when the span outgrows a byte)."""
+        n = len(sessions)
+        if n > len(self._t_free):
             raise RuntimeError("tail slab full; grow tail_capacity")
-        slot = self._t_free.pop()
-        cells = self._mint_cells(s)
-        base = int(cells.min()) if cells.size else 0
-        resid = cells - base
-        if resid.max(initial=0) <= 255:
-            self._t_u8[slot] = resid.astype(np.uint8)
-            self._t_base[slot] = base
-            self._t_wide.pop(slot, None)
-        else:
-            self._t_u8[slot] = 0
-            self._t_base[slot] = 0
-            self._t_wide[slot] = _fold_i32(cells)
-        self._t_sums[slot] = np.float32(cells.sum())
-        self._t_alive[slot] = True
-        s.slot = slot
-        s.hot = False
+        slots = np.asarray(self._t_free[len(self._t_free) - n:][::-1],
+                           np.int64)
+        del self._t_free[len(self._t_free) - n:]
+        chunk = max(1, _MINT_CELLS // self.m)
+        for a in range(0, n, chunk):
+            b = min(a + chunk, n)
+            o = offsets[a:b + 1]
+            cells = self._mint(v[a:b], o - o[0], ids[o[0]:o[-1]])
+            base = cells.min(axis=1)
+            resid = cells - base[:, None]
+            wide = resid.max(axis=1) > 255
+            resid[wide] = 0
+            at = slots[a:b]
+            self._t_u8[at] = resid.astype(np.uint8)
+            self._t_base[at] = np.where(wide, 0, base)
+            self._t_sums[at] = cells.sum(axis=1, dtype=np.int64).astype(
+                np.float32)
+            self._t_alive[at] = True
+            for i in np.flatnonzero(wide):
+                self._t_wide[int(at[i])] = _fold_i32(cells[i])
+        for s, slot in zip(sessions, slots.tolist()):
+            s.slot = slot
+            s.hot = False
         self._dirty = True
 
     def _free_slot(self, s: _Session) -> None:
@@ -319,7 +411,7 @@ class HybridEngine:
         if not s.hot:
             return
         self._hot.pop(sid)
-        self._mint_into_tail(sid, s)
+        self._place([s], *self._describe([s]))
         self.demotions += 1
         self._window_migrations += 1
         if self.obs:
@@ -393,16 +485,24 @@ class HybridEngine:
         self._dev = (jnp.asarray(u8), jnp.asarray(base), wide, order)
         self._t_order = order
         self._dirty = False
+        self.mirror_rebuilds += 1
+        if self.obs:
+            self.obs.metrics.counter("hybrid_mirror_rebuilds").inc()
         return self._dev
 
     def slab(self) -> HybridSlab:
         """The population as one hot-carrying slab (hot rows first)."""
         u8, base, wide, order = self._device_tail()
-        hot = list(self._hot.items())
-        meta = np.asarray([[s.v, s.n_private] for _, s in hot],
-                          np.int32).reshape(len(hot), 2)
-        sums = np.asarray([[self.k * (s.v + s.n_private)] for _, s in hot],
-                          np.float32).reshape(len(hot), 1)
+        # no container per row: at a 65,536-row hot set, per-row lists
+        # cost the collector hundreds of passes a sweep over the catalog
+        H = len(self._hot)
+        meta = np.empty((H, 2), np.int32)
+        meta[:, 0] = np.fromiter((s.v for s in self._hot.values()),
+                                 np.int32, H)
+        meta[:, 1] = np.fromiter((len(s.events) for s in self._hot.values()),
+                                 np.int32, H)
+        sums = (self.k * meta.sum(axis=1, keepdims=True, dtype=np.int64)
+                ).astype(np.float32)
         return HybridSlab(
             cells_u8=u8, base=base, wide=wide,
             hot_meta=meta, hot_sums=sums,
@@ -412,39 +512,62 @@ class HybridEngine:
                  bm: int | None = None) -> HybridView:
         """Classify the local clock against every session in ONE fused
         device sweep: exact verdicts (fp ≡ 0) for the hot set, packed
-        bloom verdicts (bit-identical to a flat slab) for the tail."""
-        slab = self.slab()
-        hot_sids = list(self._hot)
-        tail_sids = self._t_order
-        H, T = len(hot_sids), len(tail_sids)
-        query = self.local_clock()
-        if H and T:
-            res = self.engine.classify(query, slab, bn=bn, bm=bm)
-        elif T:
-            res = self.engine.classify(
-                query, PackedSlab(slab.cells_u8, slab.base, wide=slab.wide),
-                bn=bn, bm=bm)
-        elif H:
-            res = self._hot_only_result(slab)
-        else:
-            return HybridView(sids=[], hot=np.zeros(0, bool),
-                              q_le_p=np.zeros(0, bool),
-                              p_le_q=np.zeros(0, bool),
-                              fp_q_before_p=np.zeros(0, np.float32),
-                              fp_p_before_q=np.zeros(0, np.float32),
-                              sum_p=np.zeros(0, np.float32),
-                              sum_q=float(self._local_cells.sum()),
-                              engine="empty")
-        view = HybridView(
-            sids=hot_sids + tail_sids,
-            hot=np.arange(H + T) < H,
-            q_le_p=np.asarray(res.q_le_p, bool),
-            p_le_q=np.asarray(res.p_le_q, bool),
-            fp_q_before_p=np.asarray(res.fp_q_before_p, np.float32),
-            fp_p_before_q=np.asarray(res.fp_p_before_q, np.float32),
-            sum_p=np.asarray(res.sum_p, np.float32),
-            sum_q=float(np.asarray(res.sum_q)),
-            engine=res.engine or "")
+        bloom verdicts (bit-identical to a flat slab) for the tail.
+
+        Spans: ``hybrid.classify`` (``hot``, ``tail``, ``m``) holds
+        ``hybrid.slab`` (mirror check or rebuild, hot metadata), the
+        engine's ``causal.classify``, ``hybrid.view`` (one readback and
+        the view) and ``hybrid.observe`` (metrics, adaptive policy)."""
+        trace = self.obs.trace
+        with trace.span("hybrid.classify", m=self.m) as root:
+            with trace.span("hybrid.slab"):
+                slab = self.slab()
+            hot_sids = list(self._hot)
+            tail_sids = self._t_order
+            H, T = len(hot_sids), len(tail_sids)
+            root.set(hot=H, tail=T)
+            query = self.local_clock()
+            if H and T:
+                res = self.engine.classify(query, slab, bn=bn, bm=bm)
+            elif T:
+                res = self.engine.classify(
+                    query, PackedSlab(slab.cells_u8, slab.base,
+                                      wide=slab.wide), bn=bn, bm=bm)
+            elif H:
+                res = self._hot_only_result(slab)
+            else:
+                return HybridView(sids=[], hot=np.zeros(0, bool),
+                                  q_le_p=np.zeros(0, bool),
+                                  p_le_q=np.zeros(0, bool),
+                                  fp_q_before_p=np.zeros(0, np.float32),
+                                  fp_p_before_q=np.zeros(0, np.float32),
+                                  sum_p=np.zeros(0, np.float32),
+                                  sum_q=float(self._local_cells.sum()),
+                                  engine="empty")
+            with trace.span("hybrid.view"):
+                got = jax.device_get((res.q_le_p, res.p_le_q,
+                                      res.fp_q_before_p, res.fp_p_before_q,
+                                      res.sum_p, res.sum_q))
+                q_le_p, p_le_q, fp_qp, fp_pq, sum_p, sum_q = got
+                view = HybridView(
+                    sids=hot_sids + tail_sids,
+                    hot=np.arange(H + T) < H,
+                    q_le_p=np.asarray(q_le_p, bool),
+                    p_le_q=np.asarray(p_le_q, bool),
+                    fp_q_before_p=np.asarray(fp_qp, np.float32),
+                    fp_p_before_q=np.asarray(fp_pq, np.float32),
+                    sum_p=np.asarray(sum_p, np.float32),
+                    sum_q=float(sum_q),
+                    engine=res.engine or "")
+                if self.obs:
+                    self.obs.metrics.counter("hybrid_readback_bytes").inc(
+                        sum(np.asarray(x).nbytes for x in got))
+            with trace.span("hybrid.observe"):
+                self._observe(view, H, T)
+        return view
+
+    def _observe(self, view: HybridView, H: int, T: int) -> None:
+        """The observer's metrics and the adaptive policy's window."""
         if self.obs:
             self.obs.metrics.counter("hybrid_classified", path="hot").inc(H)
             self.obs.metrics.counter("hybrid_classified", path="tail").inc(T)
@@ -458,7 +581,6 @@ class HybridEngine:
                     np.clip(fps, 1e-30, 1.0))
         if self.adaptive is not None:
             self.adaptive.observe(view)
-        return view
 
     def _hot_only_result(self, slab: HybridSlab) -> ClassifyResult:
         """Host containment math for the degenerate no-tail population —
@@ -580,6 +702,7 @@ class HybridEngine:
         # fold the chain probes + local clock, then re-slot every row
         self.m = new_m
         self._probes = self._probes % new_m
+        self._prefix = np.zeros((1, new_m), np.int32)
         self._local_cells = fold_pow2(self._local_cells, new_m)
         self._t_u8 = np.zeros((self.cfg.tail_capacity, new_m), np.uint8)
         self._t_base[:] = 0
@@ -587,9 +710,8 @@ class HybridEngine:
         self._t_alive[:] = False
         self._t_wide.clear()
         self._t_free = list(range(self.cfg.tail_capacity - 1, -1, -1))
-        for sid, s in live:
-            s.slot = None
-            self._mint_into_tail(sid, s)
+        sessions = [s for _, s in live]
+        self._place(sessions, *self._describe(sessions))
         self.resizes += 1
         self._dirty = True
         if self.obs:
