@@ -72,6 +72,8 @@ class HybridSlab(PackedSlab):
     chain prefix length of the query clock this slab will be classified
     against — the exact verdicts are containment tests against it.
     Result rows come back hot-first: ``[0, H)`` hot, ``[H, H+T)`` tail.
+    ``HybridEngine.slab`` hands out its read-only hot arrays, shared by
+    every slab until the hot set or the tail mirror changes.
     """
 
     hot_meta: Optional[np.ndarray] = None   # [H, 2] int32 (v, n_private)
@@ -107,7 +109,7 @@ class HybridConfig:
 class HybridView:
     """One fused classify over the whole population (host-side)."""
 
-    sids: list
+    sids: tuple
     hot: np.ndarray               # bool per row: served by the exact path
     q_le_p: np.ndarray
     p_le_q: np.ndarray
@@ -139,6 +141,19 @@ class HybridView:
         if bool(self.p_le_q[i]) and not bool(self.q_le_p[i]):
             return float(self.fp_p_before_q[i])
         return 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class _RowSnapshot:
+    """What a sweep derives from the population alone: the hot rows'
+    metadata and the view's row order.  Rebuilt when the hot set or the
+    tail mirror changes, never changed in place, so slabs and views that
+    hold its arrays keep them; the arrays are read-only."""
+
+    hot_meta: np.ndarray          # [H, 2] int32 (v, n_private)
+    hot_sums: np.ndarray          # [H, 1] float32 shadow sums
+    sids: tuple                   # hot sids, then the tail mirror's order
+    hot: np.ndarray               # bool per row: the first H
 
 
 @dataclasses.dataclass(slots=True)
@@ -196,6 +211,9 @@ class HybridEngine:
         self._t_order: list = []        # alive sids in slot-scan order
         self._dirty = True
         self._dev = None                # (cells_u8, base, wide, sids)
+        # hot metadata and view row order, dropped on any hot-set move
+        # and on a mirror rebuild (``_row_snapshot``)
+        self._rows: Optional[_RowSnapshot] = None
         # migration window bookkeeping
         self._window_idx = 0
         self._window_touches = 0
@@ -204,6 +222,7 @@ class HybridEngine:
         self.demotions = 0
         self.resizes = 0
         self.mirror_rebuilds = 0
+        self.hot_rebuilds = 0
         self.adaptive = None
         if cfg.fp_budget is not None:
             from repro.hybrid.adaptive import AdaptiveConfig, AdaptivePolicy
@@ -316,6 +335,7 @@ class HybridEngine:
             return
         if s.hot:
             self._hot.pop(sid, None)
+            self._rows = None
         elif s.slot is not None:
             self._free_slot(s)
 
@@ -398,6 +418,7 @@ class HybridEngine:
         s.hot = True
         s.promoted_window = self._window_idx
         self._hot[sid] = s
+        self._rows = None
         self.promotions += 1
         self._window_migrations += 1
         if self.obs:
@@ -411,6 +432,7 @@ class HybridEngine:
         if not s.hot:
             return
         self._hot.pop(sid)
+        self._rows = None
         self._place([s], *self._describe([s]))
         self.demotions += 1
         self._window_migrations += 1
@@ -484,15 +506,19 @@ class HybridEngine:
                 wide[i] = self._t_wide[slot]
         self._dev = (jnp.asarray(u8), jnp.asarray(base), wide, order)
         self._t_order = order
+        self._rows = None
         self._dirty = False
         self.mirror_rebuilds += 1
         if self.obs:
             self.obs.metrics.counter("hybrid_mirror_rebuilds").inc()
         return self._dev
 
-    def slab(self) -> HybridSlab:
-        """The population as one hot-carrying slab (hot rows first)."""
-        u8, base, wide, order = self._device_tail()
+    def _row_snapshot(self) -> _RowSnapshot:
+        """The hot metadata and the view's row order, rebuilt only when
+        ``promote``, ``demote``, ``release`` of a hot session or a mirror
+        rebuild dropped them (call after ``_device_tail``)."""
+        if self._rows is not None:
+            return self._rows
         # no container per row: at a 65,536-row hot set, per-row lists
         # cost the collector hundreds of passes a sweep over the catalog
         H = len(self._hot)
@@ -503,9 +529,23 @@ class HybridEngine:
                                  np.int32, H)
         sums = (self.k * meta.sum(axis=1, keepdims=True, dtype=np.int64)
                 ).astype(np.float32)
+        hot = np.arange(H + len(self._t_order)) < H
+        for a in (meta, sums, hot):
+            a.setflags(write=False)
+        self._rows = _RowSnapshot(hot_meta=meta, hot_sums=sums,
+                                  sids=(*self._hot, *self._t_order), hot=hot)
+        self.hot_rebuilds += 1
+        if self.obs:
+            self.obs.metrics.counter("hybrid_hot_rebuilds").inc()
+        return self._rows
+
+    def slab(self) -> HybridSlab:
+        """The population as one hot-carrying slab (hot rows first)."""
+        u8, base, wide, _ = self._device_tail()
+        rows = self._row_snapshot()
         return HybridSlab(
             cells_u8=u8, base=base, wide=wide,
-            hot_meta=meta, hot_sums=sums,
+            hot_meta=rows.hot_meta, hot_sums=rows.hot_sums,
             local_version=self.local_version)
 
     def classify(self, *, bn: int | None = None,
@@ -515,16 +555,15 @@ class HybridEngine:
         bloom verdicts (bit-identical to a flat slab) for the tail.
 
         Spans: ``hybrid.classify`` (``hot``, ``tail``, ``m``) holds
-        ``hybrid.slab`` (mirror check or rebuild, hot metadata), the
+        ``hybrid.slab`` (mirror and row-snapshot checks or rebuilds), the
         engine's ``causal.classify``, ``hybrid.view`` (one readback and
         the view) and ``hybrid.observe`` (metrics, adaptive policy)."""
         trace = self.obs.trace
         with trace.span("hybrid.classify", m=self.m) as root:
             with trace.span("hybrid.slab"):
                 slab = self.slab()
-            hot_sids = list(self._hot)
-            tail_sids = self._t_order
-            H, T = len(hot_sids), len(tail_sids)
+                rows = self._row_snapshot()
+            H, T = slab.hot_count, slab.capacity
             root.set(hot=H, tail=T)
             query = self.local_clock()
             if H and T:
@@ -536,7 +575,7 @@ class HybridEngine:
             elif H:
                 res = self._hot_only_result(slab)
             else:
-                return HybridView(sids=[], hot=np.zeros(0, bool),
+                return HybridView(sids=(), hot=np.zeros(0, bool),
                                   q_le_p=np.zeros(0, bool),
                                   p_le_q=np.zeros(0, bool),
                                   fp_q_before_p=np.zeros(0, np.float32),
@@ -550,8 +589,7 @@ class HybridEngine:
                                       res.sum_p, res.sum_q))
                 q_le_p, p_le_q, fp_qp, fp_pq, sum_p, sum_q = got
                 view = HybridView(
-                    sids=hot_sids + tail_sids,
-                    hot=np.arange(H + T) < H,
+                    sids=rows.sids, hot=rows.hot,
                     q_le_p=np.asarray(q_le_p, bool),
                     p_le_q=np.asarray(p_le_q, bool),
                     fp_q_before_p=np.asarray(fp_qp, np.float32),

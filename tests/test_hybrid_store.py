@@ -15,6 +15,10 @@ The contracts under test:
 - **The sweep is spanned and counted**: ``hybrid.classify`` holds
   ``hybrid.slab``, ``causal.classify``, ``hybrid.view`` and
   ``hybrid.observe`` in that order.
+- **The row snapshot is rebuilt exactly when the population moves**:
+  the hot metadata and the view's row order equal a build from the
+  catalog after every step, are rebuilt only after a hot-set move or a
+  mirror rebuild, and never change under a view that holds them.
 """
 import sys
 from pathlib import Path
@@ -264,6 +268,112 @@ def test_sweep_spans_nest_and_counters_count():
         assert [e["name"] for e in kids] == [
             "hybrid.slab", "causal.classify", "hybrid.view",
             "hybrid.observe"]
+    assert counter("hybrid_hot_rebuilds").value == 1
     eng.promote(4)
     eng.classify()
     assert counter("hybrid_mirror_rebuilds").value == 2
+    assert counter("hybrid_hot_rebuilds").value == 2
+    assert eng.hot_rebuilds == 2
+
+
+def _from_catalog(eng):
+    """A fresh engine holding ``eng``'s catalog at its geometry and chain,
+    promoted in its hot order: every sweep input built anew."""
+    sids = list(eng.sessions)
+    v, offsets, ids = eng._describe([eng.sessions[s] for s in sids])
+    fresh = _engine(eng.cfg.tail_capacity, m=eng.m, V=eng.local_version,
+                    hot=eng.cfg.hot_capacity)
+    fresh.admit_many(sids, v, (offsets, ids))
+    for sid in eng._hot:
+        fresh.promote(sid)
+    return fresh
+
+
+def _assert_as_catalog(eng, view):
+    """The cached slab and view against the catalog, bit for bit."""
+    hot = list(eng._hot.values())
+    meta = np.asarray([[s.v, s.n_private] for s in hot],
+                      np.int32).reshape(-1, 2)
+    slab = eng.slab()
+    np.testing.assert_array_equal(slab.hot_meta, meta)
+    np.testing.assert_array_equal(
+        slab.hot_sums, (eng.k * meta.sum(axis=1, keepdims=True)).astype(
+            np.float32))
+    fresh = _from_catalog(eng)
+    want = fresh.classify()
+    assert fresh.hot_rebuilds == 1
+    assert view.sids == want.sids == (
+        *eng._hot, *(sid for sid, s in eng.sessions.items() if not s.hot))
+    np.testing.assert_array_equal(view.hot, np.arange(len(view.sids))
+                                  < len(hot))
+    for name in ("hot", "q_le_p", "p_le_q", "fp_q_before_p",
+                 "fp_p_before_q", "sum_p"):
+        np.testing.assert_array_equal(getattr(view, name),
+                                      getattr(want, name), err_msg=name)
+    assert view.sum_q == want.sum_q
+    np.testing.assert_array_equal(slab.hot_meta, fresh.slab().hot_meta)
+    np.testing.assert_array_equal(slab.hot_sums, fresh.slab().hot_sums)
+
+
+#: name -> (step, whether it moves the hot set or rebuilds the mirror)
+_STEPS = {
+    "advance_local": (lambda eng: eng.advance_local(1), False),
+    "touch": (lambda eng: (eng.touch(11), eng.touch(3)), False),
+    "promote": (lambda eng: eng.promote(10), True),
+    "demote": (lambda eng: eng.demote(0), True),
+    "release_hot": (lambda eng: eng.release(1), True),
+    "readmit_hot": (lambda eng: eng.admit_many([2], [5], ([0, 1], [[7, 9]])),
+                    True),
+    "resize_tail": (lambda eng: eng.resize_tail(64), True),
+    "release_tail": (lambda eng: eng.release(12), True),
+}
+
+
+@pytest.mark.parametrize("steps", [[name] for name in _STEPS]
+                         + [list(_STEPS)],
+                         ids=[*_STEPS, "all_in_turn"])
+def test_row_snapshot_rebuilds_exactly_when_the_population_moves(steps):
+    n = 24
+    v, offsets, ids = _population(n, 40, seed=5)
+    eng = _engine(n)
+    eng.admit_many(range(n), v, (offsets, ids))
+    for i in range(4):
+        eng.promote(i)
+    _assert_as_catalog(eng, eng.classify())
+    assert eng.hot_rebuilds == 1
+    want = 1
+    for name in steps:
+        step, moves = _STEPS[name]
+        step(eng)
+        view = eng.classify()
+        want += moves
+        assert eng.hot_rebuilds == want, name
+        _assert_as_catalog(eng, view)
+        # the next sweep, one local event on, reuses the snapshot
+        eng.advance_local(1)
+        _assert_as_catalog(eng, eng.classify())
+        assert eng.hot_rebuilds == want, name
+
+
+def test_kept_views_keep_their_rows_and_the_snapshot_is_read_only():
+    n = 24
+    v, offsets, ids = _population(n, 40, seed=6)
+    eng = _engine(n)
+    eng.admit_many(range(n), v, (offsets, ids))
+    for i in range(4):
+        eng.promote(i)
+    before = eng.classify()
+    sids, hot = before.sids, before.hot.copy()
+    assert sids[:4] == (0, 1, 2, 3) and hot.sum() == 4
+    eng.promote(10)
+    eng.demote(0)
+    after = eng.classify()
+    assert before.sids is sids and before.sids[:4] == (0, 1, 2, 3)
+    np.testing.assert_array_equal(before.hot, hot)
+    assert after.sids[:4] == (1, 2, 3, 10) and after.sids[4:] != sids[4:]
+    assert after.hot is not before.hot
+    slab = eng.slab()
+    assert slab.hot_meta is eng.slab().hot_meta
+    for arr in (slab.hot_meta, slab.hot_sums, after.hot):
+        with pytest.raises(ValueError, match="read-only"):
+            arr[0] = 1
