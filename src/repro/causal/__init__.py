@@ -17,9 +17,8 @@ Every compare engine (int32 fallback, packed u8 triangle/rectangle, MXU
 thermometer, promoted-row overlay, shard_map'd sharded paths) sits
 behind the two verbs; results carry ``.before() / .after() /
 .concurrent() / .confident(threshold)`` so the Eq. 3 gate is applied
-one way everywhere.  The pre-front-door entry points (``kernels.ops.*``
-comparison wrappers, ``core.clock.compare``) remain importable as
-bit-identical ``DeprecationWarning`` shims.
+one way everywhere.  ``CausalEngine`` is the one layer that chooses a
+kernel; ``kernels.ops`` only runs it.
 """
 from repro.causal.engine import CausalEngine, PackedSlab, compare
 from repro.causal.policy import CausalPolicy

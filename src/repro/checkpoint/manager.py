@@ -115,7 +115,7 @@ class CheckpointManager:
 
         Reads only the manifest.json files (clock snapshots are a few KB
         in §4 wire form) — this is what ``ClockRuntime.
-        classify_checkpoints`` feeds to one ``classify_vs_many`` call to
+        classify_checkpoints`` feeds to one ``causal.classify`` call to
         lineage-check a whole directory without touching state tensors.
         """
         self.wait()

@@ -4,7 +4,7 @@ MoE 160 routed top-6 + 2 shared (expert ff=1536).
 [arXiv:2405.04434; hf]  MLA: q_lora=1536, qk_nope=128, qk_rope=64,
 v_head=128; decode uses the absorbed-matmul latent-cache path.
 Deviation: the published model's layer 0 is dense (ff=12288); here all 60
-layers are MoE so the stack scans homogeneously (DESIGN.md §5).
+layers are MoE so the stack scans homogeneously.
 param_dtype bf16 + int8 optimizer state (giant-model memory policy).
 """
 from repro.models.config import ModelConfig

@@ -4,7 +4,7 @@
 positions, GELU 2-matrix MLP, multi-query attention, biases.
 max_seq raised to 40960 so the assigned decode_32k cell (learned-pos
 table lookup at position 32768) is well-defined — the published model
-stops at 8192; deviation noted in DESIGN.md.
+stops at 8192.
 """
 from repro.models.config import ModelConfig
 

@@ -4,7 +4,7 @@ MoE 8 experts top-2.
 [hf:xai-org/grok-1; unverified]  RMSNorm, rope, logit softcap 30.
 On a 16-wide model axis the 8 experts are replicated 2x (expert
 replication, round-robin by token) so expert-parallel all_to_all stays
-uniform; documented in DESIGN.md.  param_dtype bf16 + int8 opt state.
+uniform.  param_dtype bf16 + int8 opt state.
 """
 from repro.models.config import ModelConfig
 

@@ -4,7 +4,7 @@ parallel attn + mamba heads, ssm_state=16.
 [arXiv:2411.13676; hf]  Sliding-window attention (2048) in all layers
 except 3 global ones (first/middle/last); the SSM path runs in parallel
 with attention in every layer, outputs fused with per-path RMS norms and
-learned gains.  Deviations (DESIGN.md §5): no meta-tokens, no cross-layer
+learned gains.  Deviations: no meta-tokens, no cross-layer
 KV sharing.  Vocab padded 32001->32128.  Sub-quadratic (window + SSM):
 runs long_500k with all attention layers windowed + ring KV buffers.
 """

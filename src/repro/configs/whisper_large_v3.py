@@ -4,7 +4,7 @@
 feeds precomputed (1500, d_model) frame embeddings to the encoder.
 LayerNorm, GELU MLP, learned decoder positions.  Vocab padded 51866->51968
 (mesh divisibility); decoder max_seq raised for the decode_32k cell
-(published model decodes <=448 tokens; deviation noted in DESIGN.md).
+(a deviation: the published model decodes <=448 tokens).
 """
 from repro.models.config import ModelConfig
 

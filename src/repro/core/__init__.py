@@ -1,6 +1,6 @@
 """The paper's primary contribution: the Bloom Clock and its ecosystem.
 
-- ``clock``        BloomClock pytree + tick/merge/compare/fp_rate/compress
+- ``clock``        BloomClock pytree + tick/merge/ordering/fp_rate/compress
 - ``vector_clock`` exact O(N) baseline the paper compares against
 - ``hashing``      event-id mixing + double-hashed bloom indices
 - ``history``      §3 moving-window predecessor refinement
@@ -10,7 +10,6 @@
 from repro.core import clock, hashing, history, sim, vector_clock, wire  # noqa: F401
 from repro.core.clock import (  # noqa: F401
     BloomClock,
-    compare,
     fp_rate,
     merge,
     ordering,

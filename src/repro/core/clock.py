@@ -28,15 +28,13 @@ The same derivation runs inside the Pallas kernels
 The hot paths (tick / fused merge+compare) have Pallas TPU kernels in
 ``repro.kernels``; this module is the reference implementation.  For
 comparisons, the public surface is ``repro.causal`` (``causal.compare``
-for typed pairwise results, ``CausalEngine`` for the bulk verbs); the
-old ``compare`` name remains importable as a ``DeprecationWarning``
-shim over ``ordering``, the in-module reference the internal helpers
+for typed pairwise results, ``CausalEngine`` for the bulk verbs);
+``ordering`` is the in-module reference the internal helpers
 (``happened_before``, ``comparability_matrix``) build on.
 """
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from functools import partial
 from typing import Any
 
@@ -52,7 +50,6 @@ __all__ = [
     "tick",
     "merge",
     "ordering",
-    "compare",
     "Ordering",
     "fp_rate",
     "compress",
@@ -231,16 +228,6 @@ def ordering(a: BloomClock, b: BloomClock) -> Ordering:
         fp_a_before_b=fp_rate(sa, sb, a.m),
         fp_b_before_a=fp_rate(sb, sa, a.m),
     )
-
-
-def compare(a: BloomClock, b: BloomClock) -> Ordering:
-    """DEPRECATED alias of ``ordering`` — use ``repro.causal.compare``
-    (typed ``Comparison`` with accessors) or ``ordering`` directly."""
-    warnings.warn(
-        "repro.core.clock.compare is deprecated; use repro.causal.compare "
-        "(typed Comparison results) or repro.core.clock.ordering",
-        DeprecationWarning, stacklevel=2)
-    return ordering(a, b)
 
 
 def compress(c: BloomClock) -> BloomClock:

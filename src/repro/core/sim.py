@@ -616,7 +616,7 @@ def run_gossip_sim(cfg: SimConfig, n_rounds: int = 6, observer: int = 0,
 def monte_carlo_overlap(m: int, sum_a: int, sum_b: int, trials: int, seed: int = 0) -> float:
     """Empirical probability that a random clock with ``sum_b`` increments
     cell-wise dominates an independent random clock with ``sum_a`` increments
-    — the quantity Eq. 3 approximates.  Used by tests/benchmarks to validate
+    — the quantity Eq. 3 approximates.  Used by the tests to validate
     the formula (including the paper's m=6, ΣB=10, ΣA=7 -> 0.29 example).
     """
     rng = np.random.default_rng(seed)

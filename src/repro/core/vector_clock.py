@@ -1,7 +1,7 @@
 """Vector clock baseline (paper §1.2) — the structure the bloom clock replaces.
 
 Implemented with the same functional surface as ``repro.core.clock`` so the
-simulator and benchmarks can swap the two and measure the §4 trade-offs
+simulator and the tests can swap the two and measure the §4 trade-offs
 (space, comparability, exactness).  A vector clock is exact: comparisons
 have no false positives, at O(N) space per message.
 """

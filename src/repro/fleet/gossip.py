@@ -28,7 +28,6 @@ model estimate.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any, Optional
 
 import numpy as np
@@ -44,10 +43,6 @@ _FP_DEFAULT = 1e-4
 
 @dataclasses.dataclass(frozen=True)
 class GossipConfig:
-    # DEPRECATED: pass ``policy=CausalPolicy(fp_threshold=...)`` instead.
-    # The scalar duplicated the policy's gate; it keeps working (and
-    # still wins when no policy is set) but warns on explicit use.
-    fp_threshold: Optional[float] = None
     straggler_gap: float = 64.0   # clock-sum ticks below alive median
     push_back: bool = True        # write the union into accepted rows
     # the one source of truth when set: rounds gate on
@@ -70,19 +65,11 @@ class GossipConfig:
     # MERGE or concurrent peers could never reconverge.
     merge_forked: bool = False
 
-    def __post_init__(self):
-        if self.fp_threshold is not None:
-            warnings.warn(
-                "GossipConfig.fp_threshold is deprecated; pass "
-                "policy=CausalPolicy(fp_threshold=...) — the policy is "
-                "the one source of truth for the Eq. 3 gate",
-                DeprecationWarning, stacklevel=3)
-
     @property
     def fp_gate(self) -> float:
         if self.policy is not None:
             return self.policy.fp_threshold
-        return _FP_DEFAULT if self.fp_threshold is None else self.fp_threshold
+        return _FP_DEFAULT
 
 
 @dataclasses.dataclass

@@ -13,7 +13,7 @@ for the peer with the SMALLEST total sum Σp — in a hybrid population
 that peer lives in the tail, because the tiny-history sessions that
 would otherwise pin m to a huge value are served exactly by the hot
 set.  That is precisely why the hybrid engine can run a smaller m at
-an equal budget (the headline ``BENCH_hybrid.json`` demonstrates).
+an equal budget.
 
 Shrink-only by design: growth would need re-minting from event history
 (the engine CAN re-mint — it keeps exact descriptors — but a grown

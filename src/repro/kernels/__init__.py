@@ -6,9 +6,8 @@
                      the packed-u8 triangle sweep and the MXU
                      (dot_general thermometer) dominance reduction
 - ``pack``           quantized slab layout: u8 window residuals + base
-- ``autotune``       measured block-shape/engine table the wrappers use
-- ``ops``            padded/dispatched wrappers — the engine room of the
-                     ``repro.causal.CausalEngine`` front-door (the old
-                     public comparison names remain as deprecation shims)
+- ``autotune``       measured block-shape/engine table ``CausalEngine`` reads
+- ``ops``            kernel runners (pad, run, crop): they run what the
+                     ``repro.causal.CausalEngine`` front-door chose
 - ``ref``            pure-jnp oracles for tests
 """

@@ -23,13 +23,14 @@ Winners are cached in a JSON table keyed by
 (shape buckets are powers of two, rounded up, so one sweep covers a
 band of nearby shapes; the shard count is part of the key, so a 2-shard
 tune can never poison the 1-shard entry for the same global shape).
-``kernels.ops`` consults ``lookup`` on every call and falls back to
-conservative per-backend defaults when the table has no entry.
+``repro.causal.CausalEngine`` consults ``lookup`` when it resolves a
+call's engine and blocks, and falls back to conservative per-backend
+defaults when the table has no entry.
 
 The ``matrix_sharded`` op also records a per-shape **strategy**
 decision — ``ring`` (halved ppermute block-row sweep) vs ``replicated``
 (gather the slab once, run the single-device triangle engine) — which
-``ops._compare_matrix_packed_sharded`` dispatches on.  The cost model
+the engine dispatches the sharded all-pairs sweep on.  The cost model
 knows that forced-host device meshes serialize onto the host cores
 (ring collectives buy no parallelism there), so CI backends predict
 ``replicated`` while a real multi-core mesh predicts ``ring``.
@@ -58,6 +59,7 @@ from pathlib import Path
 import jax
 from jax._src.pallas.mosaic.error_handling import MosaicError
 
+from repro.kernels import ops
 from repro.kernels.template import resolve_interpret
 
 __all__ = [
@@ -76,7 +78,6 @@ __all__ = [
     "load_table",
     "save_table",
     "vmem_bytes",
-    "CACHE_STATS",
     "SEARCH_STATS",
 ]
 
@@ -135,21 +136,15 @@ def key_for(op: str, N: int, M: int, m: int, interpret: bool,
             f"|m{_bucket(m)}|s{shards}")
 
 
-# running hit/miss tally for the measured-table consults; the obs
-# metrics layer snapshots this around each front-door dispatch
-CACHE_STATS = {"hit": 0, "miss": 0}
-
-# running tallies for the two-stage search itself (same plumbing shape
-# as CACHE_STATS: the obs layer / CLI snapshot deltas around sweeps)
+# running tallies for the two-stage search (the obs layer / CLI
+# snapshot deltas around sweeps)
 SEARCH_STATS = {"candidates": 0, "pruned": 0, "measured": 0}
 
 
 def lookup(op: str, N: int, M: int, m: int, interpret: bool,
            shards: int = 1) -> dict | None:
     """Best known config for this op/shape/shard band, or None."""
-    cfg = load_table().get(key_for(op, N, M, m, interpret, shards))
-    CACHE_STATS["hit" if cfg is not None else "miss"] += 1
-    return cfg
+    return load_table().get(key_for(op, N, M, m, interpret, shards))
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +268,7 @@ def predict_sharded_cost(strategy: str, N: int, m: int, shards: int,
     if shards == 1:
         strategy = "replicated"          # a 1-wide ring is the plain sweep
     if bi is None or bj is None:
-        # the defaults ops._matrix_blocks falls back to on every backend
+        # the defaults CausalEngine.blocks falls back to on every backend
         bi = bj = 128
     tri = predict_cost("tri", N, N, m, bi, bj, bm, interpret)
     if strategy == "replicated":
@@ -344,7 +339,6 @@ def _rand_packed(N: int, m: int, span: int, seed: int = 0):
 
 def _matrix_candidates(N: int, m: int, span: int, interpret: bool) -> list:
     """The full knob grid for the matrix op (before the model prunes)."""
-    from repro.kernels import ops
     out = []
     for bi in (8, 64, 128, 256):
         for bm in (128, 256, 512, 1024):
@@ -367,7 +361,6 @@ def autotune_matrix(N: int, m: int, *, span: int = 30,
     is measured.  Pass ``explain={}`` to receive the predicted ranking,
     the survivor list, and the measured times for auditing."""
     interpret = resolve_interpret(interpret)
-    from repro.kernels import ops
     cells, base = _rand_packed(N, m, span)
     cells_i32 = cells.astype("int32")
 
@@ -388,13 +381,15 @@ def autotune_matrix(N: int, m: int, *, span: int = 30,
     for engine, bi, bj, bm in survivors:
         try:
             if engine == "i32":
-                fn = lambda: ops._compare_matrix(
-                    cells_i32, cells_i32, engine="i32", bi=bi, bj=bj,
-                    bm=bm, interpret=interpret, use_autotune=False)
+                fn = lambda: ops.compare_i32(
+                    cells_i32, cells_i32, bi=bi, bj=bj, bm=bm,
+                    interpret=interpret)
             else:
-                fn = lambda: ops._compare_matrix_packed(
+                bounds = (ops.logical_bounds(cells, base, cells, base)
+                          if engine == "mxu" else None)
+                fn = lambda: ops.compare_packed(
                     cells, base, engine=engine, bi=bi, bj=bj, bm=bm,
-                    interpret=interpret, use_autotune=False)
+                    uniform_base=True, interpret=interpret, bounds=bounds)
             dt = _measure(fn)
         except CANDIDATE_REFUSED as e:    # candidate invalid on this backend
             if verbose:
@@ -417,10 +412,10 @@ def autotune_matrix_sharded(N: int, m: int, shards: int, *, span: int = 30,
                             explain: dict | None = None):
     """Race ring vs replicated for the sharded symmetric all-pairs sweep.
 
-    Returns {"strategy", "bi", "bj", "bm", "us"} — the config
-    ``ops._compare_matrix_packed_sharded`` dispatches on."""
+    Returns {"strategy", "bi", "bj", "bm", "us"} — the config the
+    engine's sharded all-pairs sweep dispatches on.  Both strategies
+    run at the ``matrix`` entry's blocks (the defaults without one)."""
     interpret = resolve_interpret(interpret)
-    from repro.kernels import ops
     from repro.launch.mesh import make_fleet_mesh
 
     if len(jax.devices()) < shards:
@@ -443,11 +438,15 @@ def autotune_matrix_sharded(N: int, m: int, shards: int, *, span: int = 30,
             {"strategy": s, "pred_us": p * 1e6} for s, p in ranking]
 
     results = []
+    kw = dict(mesh=mesh, axis="fleet", bi=bi, bj=bj, bm=bm,
+              uniform_base=True, interpret=interpret)
     for strategy in grid:
         try:
-            fn = lambda: ops._compare_matrix_packed_sharded(
-                cells, base, mesh=mesh, axis="fleet", strategy=strategy,
-                uniform_base=True, interpret=interpret, use_autotune=False)
+            if strategy == "ring":
+                fn = lambda: ops.compare_ring(cells, base, **kw)
+            else:
+                fn = lambda: ops.compare_replicated(
+                    cells, base, engine="tri", mesh_outputs=True, **kw)
             dt = _measure(fn)
         except CANDIDATE_REFUSED as e:
             if verbose:
@@ -472,7 +471,6 @@ def autotune_one_vs_many(N: int, m: int, *, span: int = 30,
     import jax.numpy as jnp
 
     interpret = resolve_interpret(interpret)
-    from repro.kernels import ops
     cells, base = _rand_packed(N, m, span)
     q = cells[0].astype(jnp.int32)
 
@@ -500,9 +498,8 @@ def autotune_one_vs_many(N: int, m: int, *, span: int = 30,
     results = []
     for bn, bm in survivors:
         try:
-            dt = _measure(lambda: ops._classify_vs_many_packed(
-                q, cells, base, bn=bn, bm=bm, interpret=interpret,
-                use_autotune=False))
+            dt = _measure(lambda: ops.classify_packed(
+                q, cells, base, bn=bn, bm=bm, interpret=interpret))
         except CANDIDATE_REFUSED:
             continue
         results.append({"engine": "packed", "bn": bn, "bm": bm,
@@ -524,12 +521,11 @@ def autotune_hybrid(N: int, m: int, *, hot: int | None = None,
     ``N`` is the TOTAL row count; ``hot`` (default N // 8) of those are
     exact hot rows, the rest the packed bloom tail.  Winners land under
     ``key_for("hybrid", N, hot, m, ...)`` — the hot count rides in the
-    M slot — matching the ``ops._hybrid_blocks`` lookup."""
+    M slot — matching the ``CausalEngine.blocks`` lookup."""
     import jax.numpy as jnp
     import numpy as np
 
     interpret = resolve_interpret(interpret)
-    from repro.kernels import ops
     hot = hot if hot is not None else max(8, N // 8)
     T = max(8, N - hot)
     cells, base = _rand_packed(T, m, span)
@@ -559,9 +555,9 @@ def autotune_hybrid(N: int, m: int, *, hot: int | None = None,
     results = []
     for bn, bm in survivors:
         try:
-            dt = _measure(lambda: ops._classify_hybrid(
+            dt = _measure(lambda: ops.classify_hybrid(
                 q, 32, meta, hsums, cells, base, bn=bn, bm=bm,
-                interpret=interpret, use_autotune=False))
+                interpret=interpret)[0])
         except CANDIDATE_REFUSED:
             continue
         results.append({"engine": "hybrid", "bn": bn, "bm": bm,
@@ -582,9 +578,7 @@ def autotune_shapes(shapes, *, shard_counts=(), interpret: bool | None = None,
 
     ``observer`` (a ``repro.obs.Observer``) gets one ``autotune.sweep``
     span per (op, shape) with the search counters as attributes; the
-    running module-level tallies live in ``SEARCH_STATS`` (same
-    snapshot-the-deltas plumbing the dispatch metrics use for
-    ``CACHE_STATS``)."""
+    running module-level tallies live in ``SEARCH_STATS``."""
     from repro.obs import resolve
     obs = resolve(observer)
     out = {}

@@ -51,8 +51,7 @@ per-row shift before encoding; padded lanes are masked in-kernel where
 bases make zero-padding non-neutral.
 
 These engines are also the per-shard building blocks of the mesh-sharded
-registry paths (``ops.classify_vs_many_packed_sharded`` /
-``ops.compare_matrix_packed_sharded``).  Nothing in the kernel bodies is
+registry paths (``ops.classify_packed_sharded`` / ``ops.compare_ring``).  Nothing in the kernel bodies is
 placement-aware — flags are exact, so sharded results stay bit-identical
 to the single-device sweeps.
 """

@@ -58,8 +58,8 @@ def main():
                          "not clock geometry")
     ap.add_argument("--bench-serve", action="store_true",
                     help="run the serve churn benchmark (quick config) "
-                         "and exit; heavier runs via "
-                         "benchmarks/bench_serve.py")
+                         "and exit; larger runs via python -m "
+                         "repro.serve.churn")
     args = ap.parse_args()
     enable_compile_cache()
 

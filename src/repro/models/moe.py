@@ -17,7 +17,7 @@
 (params-level; round-robin routing by token parity) so EP stays uniform
 when n_experts < model-axis size (grok: 8 experts x 2 replicas on a
 16-wide axis).  Replicas start identical and diverge under training —
-an intentional capacity/load-balance variant, documented in DESIGN.md.
+an intentional capacity/load-balance variant.
 
 Both paths drop overflow tokens (capacity factor), add the standard
 load-balance auxiliary loss, and weight top-k combine by softmax gates.

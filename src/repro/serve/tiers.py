@@ -45,8 +45,6 @@ from repro.core import wire
 from repro.fleet.registry import (ClockRegistry, EvictedRow, FleetView,
                                   STATUS_NAMES, _near_wrap,
                                   view_from_classify)
-from repro.kernels import ops
-from repro.kernels.template import resolve_interpret
 from repro.obs.observer import resolve
 
 __all__ = ["TierConfig", "TieredRegistry", "TieredView"]
@@ -130,12 +128,10 @@ class TieredRegistry:
         # block (f32 accumulation order), and the autotune table is
         # keyed by slab N — per-tier resolution could tile m differently
         # per tier and break the flat-slab bit-identity contract.
-        interpret = resolve_interpret(base_pol.interpret)
-        bn, bm = ops._one_vs_many_blocks(
-            cfg.hot_capacity + cfg.warm_capacity, m, base_pol.bn,
-            base_pol.bm, interpret, base_pol.autotune)
-        self.policy = dataclasses.replace(base_pol, bn=bn, bm=bm)
-        self.blocks = (bn, bm)
+        n = cfg.hot_capacity + cfg.warm_capacity
+        blocks = CausalEngine(base_pol).blocks("one_vs_many", n, n, m)
+        self.policy = dataclasses.replace(base_pol, **blocks)
+        self.blocks = (blocks["bn"], blocks["bm"])
         self.hot = ClockRegistry(capacity=cfg.hot_capacity, m=m, k=k,
                                  policy=self.policy)
         self.hot.on_evict = self._ingest_warm

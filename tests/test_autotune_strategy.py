@@ -15,12 +15,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.causal import CausalEngine, CausalPolicy, PackedSlab
 from repro.core import clock as bc
 from repro.fleet import ClockRegistry
 from repro.kernels import autotune, ops, pack
 from repro.launch.mesh import make_fleet_mesh
 
 RNG = np.random.default_rng(7)
+MAT = {"bi": 128, "bj": 128, "bm": 512}
+
+
+def _matrix_blocks(engine, n, m, shards=1):
+    """The engine's block resolution for an [n, n] compare (interpret)."""
+    blocks = CausalEngine().blocks("matrix", n, n, m, interpret=True,
+                                   engine=engine, shards=shards)
+    return blocks["bi"], blocks["bj"], blocks["bm"]
 
 
 def _packed_slab(n: int, m: int, hi: int = 9):
@@ -70,10 +79,8 @@ def test_sharded_tune_cannot_poison_one_shard_entry(monkeypatch, tmp_path):
                            shards=4) is None
     # block resolution: the d-shard path reads ONLY the matrix_sharded
     # key at the GLOBAL shape; the 1-shard path keeps its own entry
-    assert ops._matrix_blocks("full", 512, 512, 512, None, None, None,
-                              True, shards=2) == (8, 8, 128)
-    assert ops._matrix_blocks("tri", 512, 512, 512, None, None, None,
-                              True) == (64, 64, 512)
+    assert _matrix_blocks("full", 512, 512, shards=2) == (8, 8, 128)
+    assert _matrix_blocks("tri", 512, 512) == (64, 64, 512)
 
 
 def test_per_shard_subshape_lookup_not_aliased(monkeypatch, tmp_path):
@@ -85,8 +92,7 @@ def test_per_shard_subshape_lookup_not_aliased(monkeypatch, tmp_path):
         autotune.key_for("matrix", 256, 256, 512, True):
             {"engine": "full", "bi": 8, "bj": 8, "bm": 128, "us": 1.0},
     })
-    assert ops._matrix_blocks("full", 512, 512, 512, None, None, None,
-                              True, shards=2) == (128, 128, 512)
+    assert _matrix_blocks("full", 512, 512, shards=2) == (128, 128, 512)
 
 
 # ---------------------------------------------------------------------------
@@ -97,8 +103,8 @@ def test_per_shard_subshape_lookup_not_aliased(monkeypatch, tmp_path):
                                             ("ring", "ring_full")])
 def test_strategy_dispatch_from_table(host_devices, monkeypatch, tmp_path,
                                       strategy, label):
-    """With no explicit strategy, the sharded front-door dispatches on
-    the table's matrix_sharded entry and records its decision."""
+    """The sharded front-door dispatches on the table's matrix_sharded
+    entry and labels its result with that decision."""
     n, m = 32, 128
     _plant(monkeypatch, tmp_path, {
         autotune.key_for("matrix_sharded", n, n, m, True, shards=2):
@@ -106,11 +112,13 @@ def test_strategy_dispatch_from_table(host_devices, monkeypatch, tmp_path,
              "us": 1.0},
     })
     u8, base = _packed_slab(n, m)
-    ref = jax.device_get(ops._compare_matrix_packed(u8, base))
-    got = jax.device_get(ops._compare_matrix_packed_sharded(
-        u8, base, mesh=make_fleet_mesh(2), axis="fleet"))
-    assert ops.LAST_DISPATCH["engine"].startswith(label)
-    assert ops.LAST_DISPATCH["strategy"] == strategy
+    ref = jax.device_get(ops.compare_packed(
+        u8, base, engine="tri", **MAT, uniform_base=False, interpret=True))
+    got = jax.device_get(CausalEngine(CausalPolicy(
+        mesh=make_fleet_mesh(2))).pairs(PackedSlab(u8, base)))
+    assert got.engine.startswith(label)
+    assert dict(got.blocks)["strategy"] == strategy
+    assert dict(got.blocks)["shards"] == 2
     for k in ref:
         np.testing.assert_array_equal(np.asarray(ref[k]),
                                       np.asarray(got[k]), err_msg=k)
@@ -126,21 +134,31 @@ def test_explicit_strategies_bit_identical(host_devices, shards, strategy):
         RNG.integers(0, 9, (n, m)) + RNG.integers(0, 300, (n, 1)), jnp.int32)
     u8, base, ok = pack.pack_rows(cells)
     assert bool(ok.all())
-    ref = jax.device_get(ops._compare_matrix_packed(u8, base))
-    got = jax.device_get(ops._compare_matrix_packed_sharded(
-        u8, base, mesh=make_fleet_mesh(shards), axis="fleet",
-        strategy=strategy))
+    kw = dict(**MAT, uniform_base=False, interpret=True)
+    ref = jax.device_get(ops.compare_packed(u8, base, engine="tri", **kw))
+    mesh = make_fleet_mesh(shards)
+    if strategy == "ring":
+        got = ops.compare_ring(u8, base, mesh=mesh, axis="fleet", **kw)
+    else:
+        got = ops.compare_replicated(u8, base, mesh=mesh, axis="fleet",
+                                     engine="tri", mesh_outputs=True, **kw)
+    got = jax.device_get(got)
     for k in ref:
         np.testing.assert_array_equal(np.asarray(ref[k]),
                                       np.asarray(got[k]), err_msg=k)
 
 
-def test_unknown_strategy_raises(host_devices):
+def test_unknown_strategy_raises(host_devices, monkeypatch, tmp_path):
+    """A table entry naming a strategy the engine does not know is
+    refused, not silently run as another."""
+    _plant(monkeypatch, tmp_path, {
+        autotune.key_for("matrix_sharded", 16, 16, 128, True, shards=2):
+            {"strategy": "gossip", "us": 1.0},
+    })
     u8, base = _packed_slab(16, 128)
     with pytest.raises(ValueError, match="strategy"):
-        ops._compare_matrix_packed_sharded(
-            u8, base, mesh=make_fleet_mesh(2), axis="fleet",
-            strategy="gossip")
+        CausalEngine(CausalPolicy(mesh=make_fleet_mesh(2))).pairs(
+            PackedSlab(u8, base))
 
 
 def test_registry_replicated_strategy_with_dead_and_promoted(

@@ -3,19 +3,19 @@
 Pins the acceptance contract of the front-door redesign:
 
 - ``CausalEngine.classify`` / ``.pairs`` outputs are BIT-IDENTICAL
-  (flags, Eq. 3 fp bits, sums) to the pre-refactor entry points across
-  every engine path — int32 fallback, packed triangle, MXU thermometer,
-  promoted-row overlay, and sharded {1, 2, 3, 4, 8} (3 exercises the
-  odd-d mirror shipping of the halved ppermute ring);
-- the old ``ops.*`` / ``core.clock.compare`` signatures remain
-  importable as DeprecationWarning shims that delegate bit-identically,
-  and NO internal ``repro.*`` caller still routes through them;
+  (flags, Eq. 3 fp bits, sums) to the ``kernels.ops`` runners called
+  with explicit blocks, across every engine path — int32 fallback,
+  packed triangle, MXU thermometer, promoted-row overlay, and sharded
+  {1, 2, 3, 4, 8} (3 exercises the odd-d mirror shipping of the halved
+  ppermute ring);
+- every result is labelled (``engine``, ``blocks``) by its own call,
+  also when calls of different engines interleave on two threads;
 - the typed results are real pytrees: flatten/unflatten round-trips
   under jit, vmap, and device_put onto a sharded mesh;
 - ``Comparison.confident(t)`` is equivalent to the pre-existing
   ``happened_before(a, b, threshold=t)`` decision rule (hypothesis).
 """
-import warnings
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,10 @@ from repro.launch.mesh import make_fleet_mesh
 from repro.runtime.clock_runtime import ClockConfig, ClockRuntime
 
 RNG = np.random.default_rng(42)
+# the runners' blocks the front-door picks off the chip at these small
+# shapes (no autotune entry): one-vs-many and all-pairs defaults
+OVM = {"bn": 128, "bm": 512}
+MAT = {"bi": 128, "bj": 128, "bm": 512}
 MATRIX_KEYS = ("a_le_b", "b_le_a", "concurrent", "fp", "row_sums", "col_sums")
 CLASSIFY_KEYS = ("q_le_p", "p_le_q", "sum_q", "sum_p",
                  "fp_q_before_p", "fp_p_before_q")
@@ -51,7 +55,7 @@ def _assert_bits(got, ref, keys):
 
 
 # ---------------------------------------------------------------------------
-# typed pairwise compare + shims
+# typed pairwise compare; front-door vs the kernel runners
 # ---------------------------------------------------------------------------
 
 def test_compare_typed_matches_ordering():
@@ -66,41 +70,37 @@ def test_compare_typed_matches_ordering():
     assert float(c.fp_ba) == float(o.fp_b_before_a)
 
 
-def test_clock_compare_shim_warns_and_delegates():
-    a, b = _clock(_cells(1, 64)[0]), _clock(_cells(1, 64)[0])
-    with pytest.warns(DeprecationWarning, match="clock.compare is deprecated"):
-        o = bc.compare(a, b)
-    ref = bc.ordering(a, b)
-    assert bool(o.a_le_b) == bool(ref.a_le_b)
-    assert float(o.fp_a_before_b) == float(ref.fp_a_before_b)
-
-
 def test_ops_shims_warn_and_are_bit_identical():
+    """The front-door's packed pairs and int32 classify equal the
+    runners at the blocks it names."""
     cells = _cells(9, 100)
     u8, base, ok = pack.pack_rows(cells)
     assert bool(ok.all())
     eng = causal.CausalEngine()
     got = eng.pairs(causal.PackedSlab(u8, base))
-    with pytest.warns(DeprecationWarning, match="compare_matrix_packed"):
-        ref = ops.compare_matrix_packed(u8, base)
+    assert (got.engine, dict(got.blocks)) == ("tri", MAT)
+    ref = ops.compare_packed(u8, base, engine="tri", **MAT,
+                             uniform_base=False, interpret=True)
     _assert_bits(got, ref, MATRIX_KEYS)
     cres = eng.classify(cells[0], cells)
-    with pytest.warns(DeprecationWarning, match="classify_vs_many"):
-        cref = ops.classify_vs_many(cells[0], cells)
+    assert (cres.engine, cres.blocks) == ("i32", None)
+    cref = ops.classify_i32(cells[0], cells, bn=8, bm=512, interpret=True)
     _assert_bits(cres, cref, CLASSIFY_KEYS)
 
 
 # ---------------------------------------------------------------------------
-# engine paths vs shims: i32 fallback, packed tri, mxu, forced i32
+# engine paths vs runners: i32 fallback, packed tri, mxu, forced i32
 # ---------------------------------------------------------------------------
 
 def test_pairs_auto_pack_matches_shim():
     cells = _cells(13, 129, hi=9)          # span fits a byte -> packed tri
     got = causal.CausalEngine().pairs(cells)
     assert got.engine == "tri"
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        ref = ops.compare_matrix(cells, cells)
+    lo = int(cells.min())
+    u8 = (cells - lo).astype(jnp.uint8)
+    ref = ops.compare_packed(u8, jnp.full((13,), lo, jnp.int32),
+                             engine="tri", **MAT, uniform_base=True,
+                             interpret=True)
     _assert_bits(got, ref, MATRIX_KEYS)
 
 
@@ -108,9 +108,7 @@ def test_pairs_wide_span_i32_fallback_matches_shim():
     cells = _cells(7, 65, hi=5).at[0, 0].set(100000)   # span > U8_MAX
     got = causal.CausalEngine().pairs(cells)
     assert got.engine == "i32"
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        ref = ops.compare_matrix(cells, cells)
+    ref = ops.compare_i32(cells, cells, **MAT, interpret=True)
     _assert_bits(got, ref, MATRIX_KEYS)
 
 
@@ -123,9 +121,11 @@ def test_pairs_forced_packed_engines_match_shim(engine):
     got = causal.CausalEngine(causal.CausalPolicy(engine=engine)).pairs(
         causal.PackedSlab(u8, pb))
     assert got.engine == engine
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        ref = ops.compare_matrix_packed(u8, pb, engine=engine)
+    bounds = ops.logical_bounds(u8, pb, u8, pb)
+    ref = ops.compare_packed(u8, pb, engine=engine, **MAT,
+                             uniform_base=bool((pb == pb[0]).all()),
+                             interpret=True,
+                             bounds=bounds if engine == "mxu" else None)
     _assert_bits(got, ref, MATRIX_KEYS)
 
 
@@ -133,9 +133,7 @@ def test_pairs_policy_pack_off_forces_i32():
     cells = _cells(6, 64, hi=9)
     got = causal.CausalEngine(causal.CausalPolicy(pack=False)).pairs(cells)
     assert got.engine == "i32"
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        ref = ops.compare_matrix(cells, cells, engine="i32")
+    ref = ops.compare_i32(cells, cells, **MAT, interpret=True)
     _assert_bits(got, ref, MATRIX_KEYS)
 
 
@@ -159,7 +157,8 @@ def test_pairs_wide_slab_without_base_host():
 
 def test_classify_wide_overlay_matches_shim_composition():
     """PackedSlab with a promoted row: the front-door's overlay equals
-    the shim composition (packed bulk + overlay_wide_classify)."""
+    the runners' composition (packed bulk, the promoted row through the
+    int32 runner, patched in)."""
     cells = _cells(8, 96, hi=9)
     u8, base, _ = pack.pack_rows(cells)
     wide_row = np.zeros(96, np.int64)
@@ -167,12 +166,13 @@ def test_classify_wide_overlay_matches_shim_composition():
     slab = causal.PackedSlab(u8, base, wide={3: wide_row})
     q = cells[0]
     got = causal.CausalEngine().classify(q, slab)
-    assert got.engine.endswith("+wide_overlay")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        ref = ops.classify_vs_many_packed(q, u8, base)
-        ref = ops.overlay_wide_classify(
-            ref, q, [3], jnp.asarray(wide_row[None]))
+    assert got.engine == "packed+wide_overlay"
+    ref = dict(ops.classify_packed(q, u8, base, **OVM, interpret=True))
+    wide = ops.classify_i32(q, jnp.asarray(wide_row[None], jnp.int32),
+                            bn=8, bm=512, interpret=True)
+    for key in ("q_le_p", "p_le_q", "sum_p",
+                "fp_q_before_p", "fp_p_before_q"):
+        ref[key] = ref[key].at[3].set(wide[key][0])
     _assert_bits(got, ref, CLASSIFY_KEYS)
 
 
@@ -339,14 +339,13 @@ def test_confident_equiv_happened_before_property():
 
 
 # ---------------------------------------------------------------------------
-# no internal caller routes through the shims
+# every result is labelled by its own call
 # ---------------------------------------------------------------------------
 
 def test_internal_callers_are_shim_free(tmp_path):
-    """The in-test version of the CI deprecation gate: DeprecationWarning
-    raised FROM a repro.* module becomes an error, then the fleet /
-    runtime / gossip hot paths all run — promoted rows and dead slots
-    included, so the overlay + rim dispatch is exercised too."""
+    """The fleet / runtime / gossip hot paths run — promoted rows and
+    dead slots included, so the overlay + rim dispatch is exercised —
+    and each result names the engine its own call ran."""
     m, k = 96, 3
     reg = ClockRegistry(capacity=8, m=m, k=k)
     rows = {f"p{i}": _clock(RNG.integers(0, 9, m), k) for i in range(6)}
@@ -358,17 +357,81 @@ def test_internal_callers_are_shim_free(tmp_path):
     local = bc.merge(rows["p0"], rows["p1"])
     rt = ClockRuntime(ClockConfig(m=m, k=k))
     rt.clock = local
-    with warnings.catch_warnings():
-        warnings.simplefilter("always")
-        warnings.filterwarnings("error", category=DeprecationWarning,
-                                module=r"repro\..*")
-        reg.classify_all(local)
-        reg.all_pairs()
-        gossip_round(reg, local)
-        fleet_health(reg)
-        rt.classify_fleet(reg)
-        rt.admit_merge(rows["p2"])
-        causal.CausalEngine().pairs(_cells(5, m, hi=9))
+    assert reg.classify_all(local).engine == "packed+wide_overlay"
+    pairs = reg.all_pairs()
+    assert pairs.engine == "tri+wide_rim" and pairs.blocks is None
+    _, report = gossip_round(reg, local)
+    assert report.view.engine == "packed+wide_overlay"
+    fleet_health(reg)
+    assert rt.classify_fleet(reg).engine == "packed+wide_overlay"
+    rt.admit_merge(rows["p2"])
+    res = causal.CausalEngine().pairs(_cells(5, m, hi=9))
+    assert (res.engine, dict(res.blocks)) == ("tri", MAT)
+
+
+def _labelled_call(kind):
+    """(call, expected engine, expected blocks) for one front-door path."""
+    cap, m = 16, 128
+    cells = _cells(cap, m, hi=9)
+    u8, base, _ = pack.pack_rows(cells)
+    slab = causal.PackedSlab(u8, base, base_host=np.asarray(base))
+    q = cells[0]
+    if kind == "packed":
+        eng = causal.CausalEngine()
+        return (lambda: eng.classify(q, slab), "packed",
+                tuple(sorted(OVM.items())))
+    if kind == "hybrid":
+        from repro.hybrid import HybridSlab
+        H = 8
+        meta = np.stack([np.arange(H), np.zeros(H)], 1).astype(np.int32)
+        hslab = HybridSlab(u8, base, hot_meta=meta,
+                           hot_sums=np.ones((H, 1), np.float32),
+                           local_version=4)
+        eng = causal.CausalEngine()
+        # the blocks the kernel ran with: clamped to the padded tail
+        return (lambda: eng.classify(q, hslab), "fused_hot_tail",
+                (("bm", 128), ("bn", 16), ("hot", H), ("tail", cap)))
+    if kind == "tri":
+        eng = causal.CausalEngine(causal.CausalPolicy(bi=64, bj=64))
+        return (lambda: eng.pairs(slab), "tri",
+                (("bi", 64), ("bj", 64), ("bm", 512)))
+    eng = causal.CausalEngine(causal.CausalPolicy(mesh=make_fleet_mesh(2)))
+    return (lambda: eng.pairs(slab), "ring_full",
+            tuple(sorted({**MAT, "shards": 2, "strategy": "ring"}.items())))
+
+
+@pytest.mark.parametrize("kinds", [("packed",), ("hybrid",), ("tri",),
+                                   ("ring",), ("packed", "tri"),
+                                   ("hybrid", "ring")])
+def test_result_labels_come_from_their_own_call(host_devices, kinds):
+    """Each result's ``engine``/``blocks`` is its own call's choice; two
+    kinds interleave on two threads, each labelled correctly every
+    time (a process-wide "last dispatch" would cross the labels)."""
+    calls = [_labelled_call(kind) for kind in kinds]
+    for call, engine, blocks in calls:
+        res = call()
+        assert (res.engine, res.blocks) == (engine, blocks)
+    if len(calls) == 1:
+        return
+    start = threading.Barrier(len(calls))
+    seen = [[] for _ in calls]
+
+    def run(i):
+        call = calls[i][0]
+        start.wait()
+        for _ in range(6):
+            res = call()
+            seen[i].append((res.engine, res.blocks))
+
+    threads = [threading.Thread(target=run, args=(i,))
+               for i in range(len(calls))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive()
+    for (_, engine, blocks), got in zip(calls, seen):
+        assert got == [(engine, blocks)] * 6
 
 
 # ---------------------------------------------------------------------------
@@ -382,34 +445,9 @@ def test_policy_is_single_source_of_truth():
     reg = rt.make_registry(8)
     assert reg.policy.fp_threshold == 0.5
     assert reg.policy.engine == "i32"
-    # GossipConfig: the policy's threshold wins over the legacy scalar,
-    # and explicit use of the scalar warns (deliberate shim exercise)
-    with pytest.warns(DeprecationWarning, match="fp_threshold is deprecated"):
-        cfg = GossipConfig(fp_threshold=1e-9, policy=pol)
-    assert cfg.fp_gate == 0.5
-    with pytest.warns(DeprecationWarning, match="fp_threshold is deprecated"):
-        legacy = GossipConfig(fp_threshold=1e-9)
-    assert legacy.fp_gate == 1e-9
-    assert GossipConfig().fp_gate == 1e-4    # default: no warning, old gate
-
-
-def test_gossip_policy_equivalent_to_scalar_threshold():
-    m, k = 64, 3
-    rows = {f"p{i}": _clock(RNG.integers(0, 9, m), k) for i in range(6)}
-    local = bc.merge(rows["p0"], rows["p1"])
-
-    def run(cfg):
-        reg = ClockRegistry(capacity=8, m=m, k=k)
-        reg.admit_many(rows)
-        return gossip_round(reg, local, cfg)[1]
-
-    with pytest.warns(DeprecationWarning, match="fp_threshold is deprecated"):
-        legacy_cfg = GossipConfig(fp_threshold=0.9)
-    a = run(legacy_cfg)
-    b = run(GossipConfig(policy=causal.CausalPolicy(fp_threshold=0.9)))
-    np.testing.assert_array_equal(a.accepted, b.accepted)
-    np.testing.assert_array_equal(a.unconfident, b.unconfident)
-    np.testing.assert_array_equal(a.quarantined, b.quarantined)
+    # GossipConfig gates on the policy's threshold, 1e-4 without one
+    assert GossipConfig(policy=pol).fp_gate == 0.5
+    assert GossipConfig().fp_gate == 1e-4
 
 
 def test_policy_validation_and_labels():

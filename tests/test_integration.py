@@ -319,8 +319,7 @@ class TestSimulatorVsPaper:
 
     def test_eq3_against_monte_carlo_band(self):
         """Eq. 3 is a (conservative) approximation: MC-true overlap must not
-        EXCEED the Eq. 3 prediction for these regimes (documented in
-        EXPERIMENTS.md)."""
+        EXCEED the Eq. 3 prediction for these regimes."""
         from repro.core.sim import monte_carlo_overlap
 
         for m, sa, sb in [(6, 7, 10), (64, 20, 60), (128, 50, 100)]:

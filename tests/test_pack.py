@@ -128,7 +128,9 @@ def test_packed_rect_engine_matches_reference():
     b = b.at[0].set(a[0])
     au8, ab, _ = pack.pack_rows(a)
     bu8, bb, _ = pack.pack_rows(b)
-    got = ops._compare_matrix_packed(au8, ab, bu8, bb)
+    got = ops.compare_packed(au8, ab, bu8, bb, engine="full", bi=128,
+                             bj=128, bm=512, uniform_base=False,
+                             interpret=True)
     le = jnp.all(a[:, None, :] <= b[None, :, :], axis=2)
     ge = jnp.all(a[:, None, :] >= b[None, :, :], axis=2)
     np.testing.assert_array_equal(np.asarray(got["a_le_b"]), np.asarray(le))
@@ -330,18 +332,17 @@ def test_sparse_promoted_classify_dispatch(monkeypatch):
     O(N) bulk on the packed kernel and runs the int32 kernel on just the
     [1, m] promoted handful — never on the whole materialized slab.
 
-    Spies on the INTERNAL impls the CausalEngine front-door dispatches
-    to (the public ``ops.*`` names are deprecation shims now)."""
+    Spies on the kernel runners the CausalEngine front-door calls."""
     reg = _one_wide_registry()
     calls = {"packed": [], "i32": []}
-    orig_packed = ops._classify_vs_many_packed
-    orig_i32 = ops._classify_vs_many
+    orig_packed = ops.classify_packed
+    orig_i32 = ops.classify_i32
     monkeypatch.setattr(
-        ops, "_classify_vs_many_packed",
+        ops, "classify_packed",
         lambda q, p, b, **kw: calls["packed"].append(p.shape)
         or orig_packed(q, p, b, **kw))
     monkeypatch.setattr(
-        ops, "_classify_vs_many",
+        ops, "classify_i32",
         lambda q, p, **kw: calls["i32"].append(p.shape)
         or orig_i32(q, p, **kw))
     local = reg.get("p0")
@@ -359,14 +360,14 @@ def test_sparse_promoted_all_pairs_dispatch(monkeypatch):
     engine over the packed rows and the int32 rim over [1, m] x alive."""
     reg = _one_wide_registry()
     calls = {"packed": [], "i32": []}
-    orig_packed = ops._compare_matrix_packed
-    orig_i32 = ops._compare_matrix
+    orig_packed = ops.compare_packed
+    orig_i32 = ops.compare_i32
     monkeypatch.setattr(
-        ops, "_compare_matrix_packed",
+        ops, "compare_packed",
         lambda c, b, *a, **kw: calls["packed"].append(c.shape)
         or orig_packed(c, b, *a, **kw))
     monkeypatch.setattr(
-        ops, "_compare_matrix",
+        ops, "compare_i32",
         lambda r, c, **kw: calls["i32"].append((r.shape, c.shape))
         or orig_i32(r, c, **kw))
     mats = {kk: np.asarray(v) for kk, v in reg.all_pairs().items()}
@@ -398,7 +399,7 @@ def test_sparse_promoted_all_pairs_masks_dead(monkeypatch):
 
 def test_autotune_table_miss_falls_back(tmp_path, monkeypatch):
     """No row for this backend/shape bucket: lookup reports the miss and
-    compare_matrix falls back to the built-in defaults deterministically."""
+    the engine falls back to the built-in defaults deterministically."""
     monkeypatch.setenv("REPRO_AUTOTUNE_TABLE", str(tmp_path / "missing.json"))
     assert autotune.load_table() == {}
     assert autotune.lookup("matrix", 16, 16, 128, True) is None

@@ -4,9 +4,10 @@ not attached.
 Interpret-mode tests cannot see what the chip's compiler refuses: block
 shapes off the (8, 128) tiling, ops with no Mosaic lowering, bool
 relayouts, kernels over the VMEM limit.  Each case here lowers one
-kernel through the same ops-layer padding and block resolution the TPU
-dispatch uses (``interpret=False``), at real widths, and compiles it
-for one chip of a described ``v5e:2x2`` topology.  Nothing runs.
+kernel through the same ops-layer runner the TPU dispatch uses
+(``interpret=False``), at the blocks ``CausalEngine`` resolves there and
+at real widths, and compiles it for one chip of a described ``v5e:2x2``
+topology.  Nothing runs.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler library, and every
@@ -23,7 +24,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import generate, ops
+from repro.causal import CausalEngine, CausalPolicy
+from repro.kernels import ops
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from bench.kernels import ovm  # noqa: E402
@@ -50,51 +52,42 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", enabled)
 
 
+#: the blocks ``CausalEngine`` resolves on the chip with no autotune
+#: entry (every lookup on v5e misses the interpret-only table)
+OVM = {"bn": 512, "bm": 512}
+MAT = {"bi": 128, "bj": 128, "bm": 512}
+MXU = {"bi": 128, "bj": 128, "bm": 128}
+
+
 def _one_vs_many(S, pack):
     N, m = (1 << 20, 256) if pack == "u8" else (65536, 256)
-    bn, bm = ops._one_vs_many_blocks(N, m, None, None, False, use_table=False)
     if pack == "u8":
-        return (lambda q, p, b: ops._one_vs_many_body(q, p, b, bn, bm, m,
-                                                      False),
+        return (lambda q, p, b: ops.classify_packed(q, p, b, **OVM,
+                                                    interpret=False),
                 (S((m,), jnp.int32), S((N, m), jnp.uint8),
                  S((N,), jnp.int32)))
-    return (lambda q, p: ops._classify_vs_many(q, p, bn=bn, bm=bm,
-                                               interpret=False),
+    return (lambda q, p: ops.classify_i32(q, p, **OVM, interpret=False),
             (S((m,), jnp.int32), S((N, m), jnp.int32)))
 
 
 def _matrix(S, engine, n=1024, m=1024):
-    bi, bj, bm = ops._matrix_blocks(engine, n, n, m, None, None, None,
-                                    False, use_table=False)
     u8 = (S((n, m), jnp.uint8), S((n,), jnp.int32))
     if engine == "tri":
-        return (lambda c, b: ops._tri_flags(c, b, max(bi, bj), bm, m, True,
-                                            False), u8)
-    if engine == "full":
-        return (lambda r, b, c, cb: ops._full_rect_flags(
-            r, b, c, cb, bi, bj, bm, m, True, False), u8 + u8)
-    if engine == "mxu":
-        def mxu(r, b, c, cb):
-            rp, cp, bie, bje, bme = ops._rect_tiles(r, c, bi, bj, bm, False)
-            return generate.bloom_matrix_mxu_pallas(
-                rp, cp, ops._pad_base(b, rp.shape[0]),
-                ops._pad_base(cb, cp.shape[0]), n_thresholds=64, lo=0,
-                bi=bie, bj=bje, bm=bme, m_true=m)
-        return mxu, u8 + u8
-
-    def i32(r, c):
-        rp, cp, bie, bje, bme = ops._rect_tiles(r, c, bi, bj, bm, False)
-        cs = ops.pad_to(jnp.sum(c, axis=1).astype(jnp.float32)[None, :],
-                        cp.shape[0], axis=1)
-        return generate.bloom_matrix_pallas(rp, cp, cs, bi=bie, bj=bje,
-                                            bm=bme, m_true=m)
-    return i32, (S((n, m), jnp.int32), S((n, m), jnp.int32))
+        return (lambda c, b: ops.compare_packed(
+            c, b, engine="tri", **MAT, uniform_base=False,
+            interpret=False), u8)
+    if engine in ("full", "mxu"):
+        return (lambda r, b, c, cb: ops.compare_packed(
+            r, b, c, cb, engine=engine, **(MXU if engine == "mxu" else MAT),
+            uniform_base=False, interpret=False, bounds=(0, 64)), u8 + u8)
+    return (lambda r, c: ops.compare_i32(r, c, **MAT, interpret=False),
+            (S((n, m), jnp.int32), S((n, m), jnp.int32)))
 
 
 def _hybrid(S, H=4096, T=65536, m=512):
     def fn(q, meta, hot_sums, tail, base):
-        return ops._classify_hybrid(q, 7, meta, hot_sums, tail, base,
-                                    interpret=False, use_autotune=False)
+        return ops.classify_hybrid(q, 7, meta, hot_sums, tail, base, **OVM,
+                                   interpret=False)[0]
     return fn, (S((m,), jnp.int32), S((H, 2), jnp.int32),
                 S((H,), jnp.float32), S((T, m), jnp.uint8),
                 S((T,), jnp.int32))
@@ -160,3 +153,14 @@ def test_ovm_trace_rule_matches_the_packed_kernel_only(one_chip, case):
     (line,) = _kernel_calls(one_chip, case)
     assert ovm.is_kernel(types.SimpleNamespace(name=line)) == (
         case == "one_vs_many_u8")
+
+
+@pytest.mark.parametrize("op,engine,blocks", [
+    ("one_vs_many", None, OVM), ("hybrid", None, OVM),
+    ("matrix", "tri", MAT), ("matrix", "mxu", MXU)])
+def test_chip_blocks_are_the_compiled_ones(op, engine, blocks):
+    """The blocks compiled above are the ones the engine resolves for
+    the chip when the table has no entry for it."""
+    eng = CausalEngine(CausalPolicy(autotune=False))
+    assert eng.blocks(op, 1 << 20, 4096, 1024, interpret=False,
+                      engine=engine) == blocks

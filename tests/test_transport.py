@@ -14,8 +14,6 @@
   session matches the loopback decisions on a sharded registry;
 - ClockRuntime.gossip: the transport argument end-to-end.
 """
-import dataclasses
-import warnings
 
 import jax.numpy as jnp
 import numpy as np
@@ -435,14 +433,3 @@ def test_peer_spec_parsing():
     with pytest.raises(ValueError, match="duplicate"):
         parse_peers("a@h:1,a@h:2")
 
-
-def test_gossip_config_scalar_shim_warns_once_per_construction():
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        cfg = GossipConfig()                      # defaults: silent
-        assert not caught
-        legacy = GossipConfig(fp_threshold=0.5)   # explicit scalar: warns
-    assert [w.category for w in caught] == [DeprecationWarning]
-    assert cfg.fp_gate == 1e-4 and legacy.fp_gate == 0.5
-    # dataclasses.replace re-runs the shim (frozen config stays frozen)
-    assert dataclasses.replace(AUDIT, straggler_gap=1.0).fp_gate == 1.0
