@@ -218,8 +218,10 @@ class CausalEngine:
             # under a mesh policy
             b = self.blocks("hybrid", H + N, H, m, interpret=interpret,
                             bn=bn, bm=bm)
+            # the kernel reads the [H, 2] metadata as [2, H] dense rows
+            # (a free view of the row snapshot ``HybridEngine`` keeps)
             out, (bn_run, bm_run) = ops.classify_hybrid(
-                q, int(peers.local_version), hot_meta, peers.hot_sums,
+                q, int(peers.local_version), hot_meta.T, peers.hot_sums,
                 peers.cells_u8, peers.base, **b, interpret=interpret)
             engine = "fused_hot_tail"
             blocks = _label(bn=bn_run, bm=bm_run, hot=H, tail=N)

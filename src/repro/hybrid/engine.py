@@ -150,7 +150,8 @@ class _RowSnapshot:
     tail mirror changes, never changed in place, so slabs and views that
     hold its arrays keep them; the arrays are read-only."""
 
-    hot_meta: np.ndarray          # [H, 2] int32 (v, n_private)
+    hot_meta: np.ndarray          # [H, 2] int32 (v, n_private), a view
+                                  # of [2, H] rows
     hot_sums: np.ndarray          # [H, 1] float32 shadow sums
     sids: tuple                   # hot sids, then the tail mirror's order
     hot: np.ndarray               # bool per row: the first H
@@ -521,16 +522,18 @@ class HybridEngine:
             return self._rows
         # no container per row: at a 65,536-row hot set, per-row lists
         # cost the collector hundreds of passes a sweep over the catalog
+        # the metadata is built as [2, H] dense rows, the layout the
+        # fused kernel reads, and handed out as its [H, 2] view
         H = len(self._hot)
-        meta = np.empty((H, 2), np.int32)
-        meta[:, 0] = np.fromiter((s.v for s in self._hot.values()),
-                                 np.int32, H)
-        meta[:, 1] = np.fromiter((len(s.events) for s in self._hot.values()),
-                                 np.int32, H)
+        rows = np.empty((2, H), np.int32)
+        rows[0] = np.fromiter((s.v for s in self._hot.values()), np.int32, H)
+        rows[1] = np.fromiter((len(s.events) for s in self._hot.values()),
+                              np.int32, H)
+        meta = rows.T
         sums = (self.k * meta.sum(axis=1, keepdims=True, dtype=np.int64)
                 ).astype(np.float32)
         hot = np.arange(H + len(self._t_order)) < H
-        for a in (meta, sums, hot):
+        for a in (rows, meta, sums, hot):
             a.setflags(write=False)
         self._rows = _RowSnapshot(hot_meta=meta, hot_sums=sums,
                                   sids=(*self._hot, *self._t_order), hot=hot)

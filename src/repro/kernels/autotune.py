@@ -532,8 +532,8 @@ def autotune_hybrid(N: int, m: int, *, hot: int | None = None,
     q = cells[0].astype(jnp.int32)
     rng = np.random.default_rng(1)
     meta = jnp.asarray(np.stack([rng.integers(0, 64, hot),
-                                 rng.integers(0, 4, hot)], axis=1), jnp.int32)
-    hsums = jnp.asarray(rng.integers(0, 64 * span, (hot, 1)), jnp.float32)
+                                 rng.integers(0, 4, hot)]), jnp.int32)
+    hsums = jnp.asarray(rng.integers(0, 64 * span, hot), jnp.float32)
 
     grid = []
     for bn in (8, 32, 128, 256):

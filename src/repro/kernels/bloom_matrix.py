@@ -13,11 +13,12 @@ What the engines compute (the design, shared by every instance):
 
 ``bloom_one_vs_many_pallas`` / ``bloom_one_vs_many_packed_pallas``
     grid (N/bn, m/bm); one query clock vs bn peers per step.  Dominance
-    flags AND-accumulate and sums ADD-accumulate across m-tiles into
-    per-peer [bn, 2] outputs; the Eq. 3 fp rates (both directions) are
-    applied to the total sums after the kernel.  One HBM
-    read of the peer slab total; the packed variant reads u8 residuals
-    and widens in VMEM (+ per-slot int32 base).
+    flags AND-accumulate and sums ADD-accumulate across m-tiles in VMEM
+    scratch columns, stored once per row block as [2, N] dense rows
+    (flags, sums); the Eq. 3 fp rates (both directions) are applied to
+    the total sums after the kernel.  One HBM read of the peer slab
+    total; the packed variant reads u8 residuals and widens in VMEM
+    (+ a per-slot int32 base, read as a [1, N] row).
 
 ``bloom_matrix_pallas``
     grid (N/bi, M/bj, m/bm); tiled all-pairs int32 compare with in-kernel

@@ -59,7 +59,8 @@ def bloom_one_vs_many_pallas(
     m_true: int | None = None,
     interpret: bool = False,
 ):
-    """One-vs-many classify (int32 peers): per-peer flags, sums, Eq. 3 fp."""
+    """One-vs-many classify (int32 peers): per-peer flags, sums, Eq. 3 fp,
+    each a [2, N] pair of dense rows."""
     fn = emit(CompareSpec(topology="one_vs_many", pack="i32",
                           bi=bn, bm=bm, with_stats=True))
     return fn(q, peers, m_true=m_true, interpret=interpret)
@@ -68,7 +69,7 @@ def bloom_one_vs_many_pallas(
 def bloom_one_vs_many_packed_pallas(
     q: jax.Array,        # [1, m] int32 logical query, zero-padded
     peers: jax.Array,    # [N, m] uint8 residual slab, N % bn == 0
-    base: jax.Array,     # [N, 1] int32 per-slot offsets
+    base: jax.Array,     # [1, N] int32 per-slot offsets (a dense row)
     *,
     bn: int = 8,
     bm: int = 512,
@@ -159,10 +160,10 @@ def bloom_matrix_mxu_pallas(
 def bloom_hybrid_classify_pallas(
     q: jax.Array,          # [1, m] int32 logical query, zero-padded
     v_local: jax.Array,    # [1, 1] int32 local-chain version V
-    hot_meta: jax.Array,   # [H, 2] int32 (v, n_private) per hot row
-    hot_sums: jax.Array,   # [H, 1] float32 shadow-row total sums
+    hot_meta: jax.Array,   # [2, H] int32 rows: v, n_private per hot row
+    hot_sums: jax.Array,   # [1, H] float32 shadow-row total sums
     tail: jax.Array,       # [T, m] uint8 residual slab, T % bn == 0
-    tail_base: jax.Array,  # [T, 1] int32 per-slot offsets
+    tail_base: jax.Array,  # [1, T] int32 per-slot offsets
     *,
     bn: int = 8,
     bm: int = 512,

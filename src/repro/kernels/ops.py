@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.hashing import bloom_indices
@@ -75,8 +76,9 @@ _MXU_SPAN_BUCKETS = (8, 16, 32, 64)
 def _row_align(interpret: bool, lanes: bool = False) -> int:
     """Row padding grain of a slab entering a kernel.  On the chip a u8
     tile needs 32 rows, and rows that become an output's lane axis (the
-    tri engine's square blocks, a rectangle's columns) need 128; the
-    interpreter only needs the 8-row sublane grain."""
+    tri engine's square blocks, a rectangle's columns, the one-vs-many
+    and hybrid dense result rows) need 128; the interpreter only needs
+    the 8-row sublane grain."""
     if interpret:
         return 8
     return LANE if lanes else 32
@@ -125,6 +127,20 @@ def _pad_base(base: jax.Array, n_rows: int) -> jax.Array:
     """Base lanes as the [Np, 1] int32 column the kernels expect."""
     b = jnp.asarray(base, jnp.int32).reshape(-1, 1)
     return pad_to(b, n_rows, axis=0)
+
+
+def _row(x, dtype) -> jax.Array:
+    """``x`` as a ``[1, n]`` row; a host array is reshaped before it
+    moves, and a device array of that shape and dtype is kept."""
+    if isinstance(x, np.ndarray):
+        x = x.reshape(1, -1)
+    return jnp.asarray(x, dtype).reshape(1, -1)
+
+
+def _base_row(base: jax.Array, n_cols: int) -> jax.Array:
+    """Base lanes as the [1, Np] int32 row the one-vs-many and hybrid
+    kernels expect."""
+    return pad_to(_row(base, jnp.int32), n_cols, axis=1)
 
 
 def _flat_base(base) -> jax.Array:
@@ -232,8 +248,8 @@ def classify_i32(
     (m,) = q.shape
     N, mp_ = peers.shape
     assert m == mp_, (q.shape, peers.shape)
-    peers_p, bn_eff, bm_eff = tile2d(peers, bn, bm,
-                                     row_align=_row_align(interpret))
+    peers_p, bn_eff, bm_eff = tile2d(
+        peers, bn, bm, row_align=_row_align(interpret, lanes=True))
     q_p = pad_to(q[None, :], peers_p.shape[1], axis=1)
     flags, sums, fp = bloom_one_vs_many_pallas(
         q_p, peers_p, bn=bn_eff, bm=bm_eff, m_true=m, interpret=interpret
@@ -242,13 +258,15 @@ def classify_i32(
 
 
 def _classify_dict(flags, sums, fp, N):
+    """The result dict from the kernels' [2, Np] dense rows, cropped to
+    the first N columns."""
     return {
-        "q_le_p": flags[:N, 0].astype(bool),
-        "p_le_q": flags[:N, 1].astype(bool),
+        "q_le_p": flags[0, :N].astype(bool),
+        "p_le_q": flags[1, :N].astype(bool),
         "sum_q": sums[0, 0],
-        "sum_p": sums[:N, 1],
-        "fp_q_before_p": fp[:N, 0],
-        "fp_p_before_q": fp[:N, 1],
+        "sum_p": sums[1, :N],
+        "fp_q_before_p": fp[0, :N],
+        "fp_p_before_q": fp[1, :N],
     }
 
 
@@ -256,14 +274,14 @@ def _one_vs_many_body(q, peers, base, bn, bm, m: int, interpret: bool):
     """Pad one packed slab (or one row shard of it) and run the kernel;
     shared by the unsharded and shard_map'ed classify runners."""
     nd = peers.shape[0]
-    peers_p, bn_eff, bm_eff = tile2d(peers, bn, bm,
-                                     row_align=_row_align(interpret))
+    peers_p, bn_eff, bm_eff = tile2d(
+        peers, bn, bm, row_align=_row_align(interpret, lanes=True))
     q_p = pad_to(q[None, :], peers_p.shape[1], axis=1)
-    base_p = _pad_base(base, peers_p.shape[0])
+    base_p = _base_row(base, peers_p.shape[0])
     flags, sums, fp = bloom_one_vs_many_packed_pallas(
         q_p, peers_p, base_p, bn=bn_eff, bm=bm_eff, m_true=m,
         interpret=interpret)
-    return flags[:nd], sums[:nd], fp[:nd]
+    return flags[:, :nd], sums[:, :nd], fp[:, :nd]
 
 
 def classify_packed(
@@ -328,7 +346,7 @@ def _sharded_classify_fn(mesh, axis: str, bn: int, bm: int, m: int,
     return jax.jit(jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), P(axis, None), P(axis)),
-        out_specs=(P(axis, None),) * 3,
+        out_specs=(P(None, axis),) * 3,      # [2, N] dense rows
         check_vma=False,     # no replication rule for pallas_call
     ))
 
@@ -340,8 +358,8 @@ def _sharded_classify_fn(mesh, axis: str, bn: int, bm: int, m: int,
 def classify_hybrid(
     q: jax.Array,            # [m] int32 local (query) logical cells
     v_local: int,            # local-chain version V the hot rows are vs
-    hot_meta: jax.Array,     # [H, 2] int32 (v, n_private) exact rows
-    hot_sums: jax.Array,     # [H] (or [H, 1]) f32 shadow-row total sums
+    hot_meta: jax.Array,     # [2, H] int32 rows: v, n_private per hot row
+    hot_sums: jax.Array,     # [H] (or [H, 1], [1, H]) f32 shadow sums
     tail: jax.Array,         # [T, m] uint8 residual slab
     tail_base: jax.Array,    # [T] (or [T, 1]) int32 per-slot offsets
     *,
@@ -360,30 +378,28 @@ def classify_hybrid(
     H+T rows, hot first, and the blocks the kernel ran with.
     """
     (m,) = q.shape
-    H = hot_meta.shape[0]
+    H = hot_meta.shape[1]
     T, mt_ = tail.shape
     assert m == mt_, (q.shape, tail.shape)
     assert H > 0 and T > 0, "hybrid needs both a hot set and a tail " \
         "(route single-representation slabs through the plain engines)"
-    tail_p, bn_eff, bm_eff = tile2d(tail, bn, bm,
-                                    row_align=_row_align(interpret))
+    tail_p, bn_eff, bm_eff = tile2d(
+        tail, bn, bm, row_align=_row_align(interpret, lanes=True))
     q_p = pad_to(q[None, :], tail_p.shape[1], axis=1)
-    base_p = _pad_base(tail_base, tail_p.shape[0])
-    # pad hot rows to the tile grain with (v=0, n_private=0) filler —
+    base_p = _base_row(tail_base, tail_p.shape[0])
+    # pad hot columns to the tile grain with (v=0, n_private=0) filler —
     # cropped below, never observable
-    meta_p = pad_to(jnp.asarray(hot_meta, jnp.int32), bn_eff, axis=0)
-    hsum_p = pad_to(
-        jnp.asarray(hot_sums, jnp.float32).reshape(-1, 1), bn_eff, axis=0)
+    meta_p = pad_to(jnp.asarray(hot_meta, jnp.int32), bn_eff, axis=1)
+    hsum_p = pad_to(_row(hot_sums, jnp.float32), bn_eff, axis=1)
     vloc = jnp.full((1, 1), v_local, jnp.int32)
     DISPATCHES[("hybrid", "fused_hot_tail", interpret)] += 1
-    flags, sums, fp = bloom_hybrid_classify_pallas(
+    out = bloom_hybrid_classify_pallas(
         q_p, vloc, meta_p, hsum_p, tail_p, base_p,
         bn=bn_eff, bm=bm_eff, m_true=m, interpret=interpret)
-    Hp = meta_p.shape[0]
-    flags = jnp.concatenate([flags[:H], flags[Hp:Hp + T]], axis=0)
-    sums = jnp.concatenate([sums[:H], sums[Hp:Hp + T]], axis=0)
-    fp = jnp.concatenate([fp[:H], fp[Hp:Hp + T]], axis=0)
-    return _classify_dict(flags, sums, fp, H + T), (bn_eff, bm_eff)
+    Hp = meta_p.shape[1]
+    if Hp != H:
+        out = [jnp.concatenate([x[:, :H], x[:, Hp:]], axis=1) for x in out]
+    return _classify_dict(*out, H + T), (bn_eff, bm_eff)
 
 
 # ---------------------------------------------------------------------------

@@ -34,9 +34,13 @@ reference expression (``kernels.ref.eq3_fp``):
 ``emit(spec)`` validates the spec and returns a jitted wrapper whose
 outputs are BIT-IDENTICAL to the hand-rolled kernel the spec names
 (pinned by tests/test_template.py against verbatim copies of the
-pre-refactor kernels).  ``kernels.generate`` builds the named engine
-instances the rest of the system imports; nothing outside this pair
-defines a kernel body anymore.
+pre-refactor kernels).  The one-vs-many and hybrid instances read
+per-row vectors (bases, hot metadata) and write their results as dense
+rows, ``[1|2, N]`` with the row index along lanes: their ``[2, N]``
+results are the legacy ``[N, 2]`` ones transposed, bit for bit.
+``kernels.generate`` builds the named engine instances the rest of the
+system imports; nothing outside this pair defines a kernel body
+anymore.
 
 The generator refuses, at emission/call time, any knob combination
 whose per-grid-step VMEM estimate (``vmem_estimate``) exceeds the
@@ -177,19 +181,23 @@ def vmem_estimate(spec: CompareSpec) -> int:
 
     Operand tiles are multiplied by the pipeline depth (Mosaic keeps
     ``depth`` tiles in flight when axes are parallel); output blocks are
-    double-buffered; int32 intermediates are single-buffered.  Narrow
-    ``[rows, 1|2]`` blocks occupy whole 128-lane rows in VMEM.
+    double-buffered; int32 intermediates and scratch are single-buffered.
+    Narrow ``[rows, 1|2]`` blocks and scratch columns occupy whole
+    128-lane rows in VMEM; ``[1|2, cols]`` row blocks occupy 8 sublanes.
     """
     bi, bj, bm, d = spec.bi, spec.bj, spec.bm, spec.pipeline_depth
     if spec.topology in ("one_vs_many", "hybrid"):
         esize = 1 if spec.pack == "u8" else 4
-        # query row, peer tile, base column
-        operands = bm * 4 + bi * bm * esize + bi * _LANES * 4
+        row = _SUBLANES * bi * 4                      # one [1|2, bn] block
+        # query row, peer tile, base row
+        operands = _SUBLANES * bm * 4 + bi * bm * esize + row
         if spec.topology == "hybrid":
-            operands += 2 * bi * _LANES * 4           # meta + hot-sum tiles
+            operands += 2 * row                       # meta + hot-sum rows
         work = 3 * bi * bm * 4         # widened tile, difference, mask
-        outputs = 2 * 2 * bi * _LANES * 4             # flags + sums
-        return operands * d + work + outputs
+        # base column, flag and sum_p accumulators, the sum_q cell
+        scratch = 3 * bi * _LANES * 4 + _SUBLANES * _LANES * 4
+        outputs = 2 * 2 * row                         # flags + sums rows
+        return operands * d + work + scratch + outputs
     if spec.topology == "mxu":
         operands = (bi + bj) * bm + (bi + bj) * _LANES * 4
         # widened values + one threshold's f32 encodings
@@ -254,6 +262,7 @@ def _compiler_params(spec: CompareSpec, n_axes: int, interpret: bool):
 # ---------------------------------------------------------------------------
 
 _LANES = 128
+_SUBLANES = 8
 _CHUNK = 8                 # left-tile rows per pairwise difference
 _PAD_LO, _PAD_HI = -(1 << 30), 1 << 30   # neutral values for max / min
 
@@ -270,8 +279,16 @@ def _two_lanes(x0, x1):
     return jnp.where(lane == 0, x0, x1)
 
 
+def _two_rows(x0, x1):
+    """[2, cols] from two [1, cols|1] rows, without a concatenate."""
+    cols = max(x0.shape[1], x1.shape[1])
+    row = jax.lax.broadcasted_iota(jnp.int32, (2, cols), 0)
+    return jnp.where(row == 0, x0, x1)
+
+
 def _accumulate(j, flags_ref, sums_ref, flags, sums):
-    """AND flags / add sums across m-tiles into the revisited blocks."""
+    """AND flags / add sums across m-tiles into revisited blocks (or
+    VMEM scratch)."""
     @pl.when(j == 0)
     def _init():
         flags_ref[...] = flags
@@ -361,11 +378,52 @@ def _row_sum(x):
     return jnp.sum(x, axis=1, keepdims=True).astype(jnp.float32)
 
 
-def _eq3_pairs(sums, m: int):
-    """[N, 2] Eq. 3 fp (q before p, p before q) from [N, 2] total sums
-    (sum_q, sum_p) — the reference expression, applied once by XLA."""
-    return jnp.stack([eq3_fp(sums[:, 0], sums[:, 1], m),
-                      eq3_fp(sums[:, 1], sums[:, 0], m)], axis=1)
+def _eq3_pairs(sums, m: int, axis: int = 1):
+    """Eq. 3 fp (q before p, p before q) from the total sums (sum_q,
+    sum_p) stacked along ``axis``: ``[N, 2]`` pairs (axis 1) or ``[2,
+    N]`` dense rows (axis 0) — the reference expression, applied once
+    by XLA."""
+    sq = jax.lax.index_in_dim(sums, 0, axis, keepdims=False)
+    sp = jax.lax.index_in_dim(sums, 1, axis, keepdims=False)
+    return jnp.stack([eq3_fp(sq, sp, m), eq3_fp(sp, sq, m)], axis=axis)
+
+
+def _rows_step(j, n_mtiles, scratch, flags_ref, sums_ref, flags, sq, sp):
+    """One m-tile of a one-vs-many row block, written as dense rows.
+
+    ``flags`` ([bn, 2]) AND-accumulates and ``sp`` ([bn, 1] sum_p) adds
+    into VMEM scratch columns, ``sq`` ([1, 1] sum_q) into one scratch
+    cell: the same int32 reductions and f32 additions, in the same
+    order, as a revisited ``[bn, 2]`` output block.  At the last m-tile
+    one transpose turns the columns into the ``(2, bn)`` output blocks
+    (flags: q ≼ p, p ≼ q; sums: sum_q, sum_p), so no per-row vector
+    crosses the kernel boundary padded to 128 lanes."""
+    flags_acc, sq_acc, sp_acc = scratch
+    _accumulate(j, flags_acc, sp_acc, flags, sp)
+    _accumulate_sq(j, sq_acc, sq)
+
+    @pl.when(j == n_mtiles - 1)
+    def _store():
+        flags_ref[...] = flags_acc[...].T
+        sums_ref[...] = _two_rows(sq_acc[...], sp_acc[...].T)
+
+
+def _accumulate_sq(j, sq_acc, sq):
+    """Add one m-tile's [1, 1] sum_q into its scratch cell."""
+    @pl.when(j == 0)
+    def _init():
+        sq_acc[...] = sq
+
+    @pl.when(j > 0)
+    def _acc():
+        sq_acc[...] = sq_acc[...] + sq
+
+
+def _rows_scratch(bn: int, acc) -> list:
+    """VMEM scratch of ``_rows_step``: flag and sum_p columns, the sum_q
+    cell."""
+    return [pltpu.VMEM((bn, 2), acc), pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((bn, 1), jnp.float32)]
 
 
 # ---------------------------------------------------------------------------
@@ -617,27 +675,34 @@ def _emit_one_vs_many(spec: CompareSpec):
     bn, bm, packed = spec.bi, spec.bm, spec.pack == "u8"
     acc = spec.acc_dtype
 
-    def kernel(q_ref, p_ref, *rest, m):
+    def kernel(q_ref, p_ref, *rest, m, n_mtiles):
         if packed:
-            pbase_ref, flags_ref, sums_ref = rest
+            pbase_ref, flags_ref, sums_ref, pbase_col, *scratch = rest
         else:
-            flags_ref, sums_ref = rest
+            flags_ref, sums_ref, *scratch = rest
         j = pl.program_id(1)
         q = q_ref[...]                                 # [1, bm] int32
         if packed:
+            @pl.when(j == 0)
+            def _base_column():
+                pbase_col[...] = pbase_ref[...].T      # [1, bn] -> [bn, 1]
+
             # widen the u8 peer tile in VMEM; HBM reads stay 1 B/cell
-            p = p_ref[...].astype(jnp.int32) + pbase_ref[...]
+            p = p_ref[...].astype(jnp.int32) + pbase_col[...]
             col = jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1) + j * bm
             p = jnp.where(col < m, p, 0)               # neutral pad lanes
         else:
             p = p_ref[...]                             # [bn, bm] int32
-        _accumulate(j, flags_ref, sums_ref, _one_vs_many_flags(q, p, acc),
-                    _two_lanes(_row_sum(q), _row_sum(p)))
+        _rows_step(j, n_mtiles, scratch, flags_ref, sums_ref,
+                   _one_vs_many_flags(q, p, acc), _row_sum(q), _row_sum(p))
 
     @functools.partial(jax.jit, static_argnames=("m_true", "interpret"))
     def one_vs_many_pallas(q, peers, base=None, *, m_true=None,
                            interpret=False):
-        """One-vs-many classify: per-peer flags, total sums, Eq. 3 fp."""
+        """One-vs-many classify of ``q`` ([1, m]) against ``peers`` ([N,
+        m]; packed: plus ``base``, a [1, N] int32 row).  Returns flags
+        ([2, N]: q ≼ p, p ≼ q), total sums ([2, N] f32: sum_q, sum_p)
+        and Eq. 3 fp ([2, N]: q before p, p before q), all dense rows."""
         validate(spec, _backend(interpret))
         N, m = peers.shape
         assert q.shape == (1, m) and m % bm == 0 and N % bn == 0
@@ -647,26 +712,30 @@ def _emit_one_vs_many(spec: CompareSpec):
             pl.BlockSpec((bn, bm), lambda i, j: (i, j)),
         ]
         operands = [q, peers]
+        scratch = _rows_scratch(bn, acc)
         if packed:
-            in_specs.append(pl.BlockSpec((bn, 1), lambda i, j: (i, 0)))
+            assert base.shape == (1, N), (base.shape, N)
+            in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, i)))
             operands.append(base)
+            scratch.insert(0, pltpu.VMEM((bn, 1), jnp.int32))
         flags, sums = pl.pallas_call(
-            functools.partial(kernel, m=m_true),
+            functools.partial(kernel, m=m_true, n_mtiles=m // bm),
             grid=(N // bn, m // bm),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((bn, 2), lambda i, j: (i, 0)),
-                pl.BlockSpec((bn, 2), lambda i, j: (i, 0)),
+                pl.BlockSpec((2, bn), lambda i, j: (0, i)),
+                pl.BlockSpec((2, bn), lambda i, j: (0, i)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((N, 2), acc),
-                jax.ShapeDtypeStruct((N, 2), jnp.float32),
+                jax.ShapeDtypeStruct((2, N), acc),
+                jax.ShapeDtypeStruct((2, N), jnp.float32),
             ],
+            scratch_shapes=scratch,
             interpret=interpret,
             name=kernel_name(spec),
             **_compiler_params(spec, 2, interpret),
         )(*operands)
-        return flags, sums, _eq3_pairs(sums, m_true)
+        return flags, sums, _eq3_pairs(sums, m_true, axis=0)
 
     return one_vs_many_pallas
 
@@ -676,9 +745,11 @@ def _emit_hybrid(spec: CompareSpec):
     acc = spec.acc_dtype
 
     def kernel(vloc_ref, q_ref, meta_ref, hsum_ref, p_ref, pbase_ref,
-               flags_ref, sums_ref, *, m, nh_tiles):
+               flags_ref, sums_ref, pbase_col, *scratch, m, nh_tiles,
+               n_mtiles):
         i = pl.program_id(0)
         j = pl.program_id(1)
+        _, sq_acc, _ = scratch                         # _rows_scratch
         q = q_ref[...]                                 # [1, bm] int32
         sq = _row_sum(q)
 
@@ -688,82 +759,96 @@ def _emit_hybrid(spec: CompareSpec):
             # minting-chain prefix length, n_private = events past the
             # prefix); against the local chain at version V the order
             # is an integer compare — no bloom cells, no Eq. 3 exposure.
-            V = vloc_ref[0, 0]
-            v = meta_ref[:, 0:1]
-            npriv = meta_ref[:, 1:2]
-            flags = _two_lanes(_flag(V <= v, acc),     # local ≼ peer
-                               _flag(jnp.logical_and(v <= V, npriv == 0),
-                                     acc))             # peer ≼ local
-            # sums[:, 0] accumulates sum(q) per m-tile for hot rows too,
-            # so the caller's sum_q (read off row 0) matches the tail
-            # engines bit for bit; sums[:, 1] of a hot row is its
-            # precomputed shadow sum, added once on the first m-tile.
-            _accumulate(j, flags_ref, sums_ref, flags,
-                        _two_lanes(sq, jnp.where(j == 0, hsum_ref[...],
-                                                 0.0)))
+            # Nothing here depends on the m-tile but sum_q, so the rows
+            # are written once, at the last m-tile.
+            _accumulate_sq(j, sq_acc, sq)
+
+            @pl.when(j == n_mtiles - 1)
+            def _store():
+                V = vloc_ref[0, 0]
+                v = meta_ref[0:1, :]                   # [1, bn] rows
+                npriv = meta_ref[1:2, :]
+                flags_ref[...] = _two_rows(
+                    _flag(V <= v, acc),                # local ≼ peer
+                    _flag(jnp.logical_and(v <= V, npriv == 0),
+                          acc))                        # peer ≼ local
+                # sum_q accumulates per m-tile for hot rows too, so the
+                # caller's sum_q (read off row 0) matches the tail
+                # engines bit for bit; sum_p of a hot row is its
+                # precomputed shadow sum
+                sums_ref[...] = _two_rows(sq_acc[...], hsum_ref[...])
 
         @pl.when(i >= nh_tiles)
         def _tail():
             # the UNMODIFIED packed one-vs-many math: tail verdicts /
             # sums stay bit-identical to the flat slab
-            p = p_ref[...].astype(jnp.int32) + pbase_ref[...]
+            @pl.when(j == 0)
+            def _base_column():
+                pbase_col[...] = pbase_ref[...].T      # [1, bn] -> [bn, 1]
+
+            p = p_ref[...].astype(jnp.int32) + pbase_col[...]
             col = jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1) + j * bm
             p = jnp.where(col < m, p, 0)               # neutral pad lanes
-            _accumulate(j, flags_ref, sums_ref,
-                        _one_vs_many_flags(q, p, acc),
-                        _two_lanes(sq, _row_sum(p)))
+            _rows_step(j, n_mtiles, scratch, flags_ref, sums_ref,
+                       _one_vs_many_flags(q, p, acc), sq, _row_sum(p))
 
     @functools.partial(jax.jit, static_argnames=("m_true", "interpret"))
     def hybrid_pallas(q, v_local, hot_meta, hot_sums, tail, tail_base, *,
                       m_true=None, interpret=False):
         """One query vs [exact hot rows ++ packed tail] in one sweep.
 
-        Outputs are stacked hot-first: rows [0, H) are the hot set
-        (exact flags, fp ≡ 0.0), rows [H, H+T) the packed tail (flags/
-        sums/fp bit-identical to the one_vs_many packed engine)."""
+        ``hot_meta`` is [2, H] int32 (rows v, n_private), ``hot_sums``
+        [1, H] f32 and ``tail_base`` [1, T] int32.  Outputs are [2, H+T]
+        dense rows as in ``one_vs_many``, stacked hot-first: columns [0,
+        H) are the hot set (exact flags, fp ≡ 0.0), [H, H+T) the packed
+        tail (flags/sums/fp bit-identical to the one_vs_many packed
+        engine)."""
         validate(spec, _backend(interpret))
-        H = hot_meta.shape[0]
+        H = hot_meta.shape[1]
         T, m = tail.shape
         assert q.shape == (1, m) and m % bm == 0, (q.shape, m, bm)
         assert H % bn == 0 and T % bn == 0 and H > 0 and T > 0, (H, T, bn)
-        assert hot_meta.shape == (H, 2) and hot_sums.shape == (H, 1)
-        assert v_local.shape == (1, 1)
+        assert hot_meta.shape == (2, H) and hot_sums.shape == (1, H)
+        assert tail_base.shape == (1, T) and v_local.shape == (1, 1)
         nh_tiles = H // bn
         m_true = m_true if m_true else m
-        body = functools.partial(kernel, m=m_true, nh_tiles=nh_tiles)
+        body = functools.partial(kernel, m=m_true, nh_tiles=nh_tiles,
+                                 n_mtiles=m // bm)
         # Hot tiles clamp the tail index maps to block 0 (and vice
         # versa): every grid step fetches valid blocks, and only the
         # branch of its own side runs.
         in_specs = [
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bm), lambda i, j: (0, j)),
-            pl.BlockSpec((bn, 2),
-                         lambda i, j: (jnp.minimum(i, nh_tiles - 1), 0)),
-            pl.BlockSpec((bn, 1),
-                         lambda i, j: (jnp.minimum(i, nh_tiles - 1), 0)),
+            pl.BlockSpec((2, bn),
+                         lambda i, j: (0, jnp.minimum(i, nh_tiles - 1))),
+            pl.BlockSpec((1, bn),
+                         lambda i, j: (0, jnp.minimum(i, nh_tiles - 1))),
             pl.BlockSpec((bn, bm),
                          lambda i, j: (jnp.maximum(i - nh_tiles, 0), j)),
-            pl.BlockSpec((bn, 1),
-                         lambda i, j: (jnp.maximum(i - nh_tiles, 0), 0)),
+            pl.BlockSpec((1, bn),
+                         lambda i, j: (0, jnp.maximum(i - nh_tiles, 0))),
         ]
         flags, sums = pl.pallas_call(
             body,
             grid=(nh_tiles + T // bn, m // bm),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((bn, 2), lambda i, j: (i, 0)),
-                pl.BlockSpec((bn, 2), lambda i, j: (i, 0)),
+                pl.BlockSpec((2, bn), lambda i, j: (0, i)),
+                pl.BlockSpec((2, bn), lambda i, j: (0, i)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((H + T, 2), acc),
-                jax.ShapeDtypeStruct((H + T, 2), jnp.float32),
+                jax.ShapeDtypeStruct((2, H + T), acc),
+                jax.ShapeDtypeStruct((2, H + T), jnp.float32),
             ],
+            scratch_shapes=[pltpu.VMEM((bn, 1), jnp.int32),
+                            *_rows_scratch(bn, acc)],
             interpret=interpret,
             name=kernel_name(spec),
             **_compiler_params(spec, 2, interpret),
         )(v_local, q, hot_meta, hot_sums, tail, tail_base)
-        hot = jnp.arange(H + T)[:, None] < H
-        fp = jnp.where(hot, 0.0, _eq3_pairs(sums, m_true))
+        hot = jnp.arange(H + T)[None, :] < H
+        fp = jnp.where(hot, 0.0, _eq3_pairs(sums, m_true, axis=0))
         return flags, sums, fp
 
     return hybrid_pallas

@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import causal
 from repro.core import clock as bc
-from repro.kernels import pack
+from repro.kernels import ops, pack
 from repro.kernels.generate import (
     ENGINE_SPECS,
     bloom_matrix_mxu_pallas,
@@ -36,6 +36,7 @@ from repro.kernels.generate import (
     bloom_one_vs_many_packed_pallas,
     bloom_one_vs_many_pallas,
 )
+from repro.kernels.ref import eq3_fp
 from repro.kernels.template import (
     VMEM_BUDGET,
     CompareSpec,
@@ -530,13 +531,19 @@ def test_template_i32_stats_pins_legacy():
         "i32-stats")
 
 
+def _transposed(outs):
+    """The legacy kernels' [N, 2] outputs as the [2, N] dense rows the
+    emitted one-vs-many instances return."""
+    return [x.T for x in outs]
+
+
 def test_template_one_vs_many_i32_pins_legacy():
     peers = jnp.asarray(RNG.integers(0, 9, (N, m)), jnp.int32)
     q = jnp.asarray(RNG.integers(0, 9, (1, m)), jnp.int32)
     kw = dict(bn=8, bm=BM, m_true=m, interpret=True)
     _assert_bit_identical(
         bloom_one_vs_many_pallas(q, peers, **kw),
-        _legacy_one_vs_many_pallas(q, peers, **kw),
+        _transposed(_legacy_one_vs_many_pallas(q, peers, **kw)),
         "one_vs_many-i32")
 
 
@@ -545,9 +552,132 @@ def test_template_one_vs_many_packed_pins_legacy():
     q = jnp.asarray(RNG.integers(0, 9, (1, m)), jnp.int32)
     kw = dict(bn=8, bm=BM, m_true=m - 3, interpret=True)
     _assert_bit_identical(
-        bloom_one_vs_many_packed_pallas(q, rows, rb, **kw),
-        _legacy_one_vs_many_packed_pallas(q, rows, rb, **kw),
+        bloom_one_vs_many_packed_pallas(q, rows, rb.reshape(1, -1), **kw),
+        _transposed(_legacy_one_vs_many_packed_pallas(q, rows, rb, **kw)),
         "one_vs_many-packed")
+
+
+# ---------------------------------------------------------------------------
+# one-vs-many and hybrid dense-row results vs plain references, bit for
+# bit, through the ops runners (ragged m, rows and hot set; int32 wrap)
+# ---------------------------------------------------------------------------
+
+INT32_MAX = 2**31 - 1
+
+
+def _wrap32(x):
+    """int64 values wrapped onto int32, as the kernels' int32 adds do."""
+    return ((np.asarray(x, np.int64) + 2**31) % 2**32 - 2**31).astype(
+        np.int32)
+
+
+def _chunked_sums(rows, bm):
+    """Row sums as the kernels define them: an int32 (wrapping) sum per
+    bm-cell chunk, converted to f32 and added in chunk order."""
+    acc = None
+    for c in range(0, rows.shape[1], bm):
+        s = _wrap32(rows[:, c:c + bm].astype(np.int64).sum(axis=1))
+        s = s.astype(np.float32)
+        acc = s if acc is None else acc + s
+    return acc
+
+
+def _ovm_reference(q, logical, bm):
+    """Plain one-vs-many reference: int32 wrap-subtraction dominance,
+    chunked sums, Eq. 3 by the reference expression."""
+    d = _wrap32(logical.astype(np.int64) - q.astype(np.int64))
+    sum_q = _chunked_sums(q[None, :], bm)[0]
+    sum_p = _chunked_sums(logical, bm)
+    sq = np.full_like(sum_p, sum_q)
+    m = q.shape[0]
+    return {
+        "q_le_p": d.min(axis=1) >= 0,
+        "p_le_q": d.max(axis=1) <= 0,
+        "sum_q": sum_q,
+        "sum_p": sum_p,
+        "fp_q_before_p": np.asarray(eq3_fp(sq, sum_p, m)),
+        "fp_p_before_q": np.asarray(eq3_fp(sum_p, sq, m)),
+    }
+
+
+def _ordered_rows(rng, n, m, lo):
+    """(q, u8, base): a third of the rows at or above q, a third at or
+    below it, the rest drawn at random, every value ``lo`` plus a byte."""
+    qu = rng.integers(2, 120, m)
+    u8 = rng.integers(0, 124, (n, m))
+    u8[0::3] = qu + rng.integers(0, 3, u8[0::3].shape)
+    u8[1::3] = qu - rng.integers(0, 3, u8[1::3].shape)
+    base = np.full(n, lo, np.int64)
+    base[2::3] += rng.integers(0, 3, base[2::3].shape)
+    return (_wrap32(qu + lo), u8.astype(np.uint8), _wrap32(base))
+
+
+#: (rows, m, bn, bm, base of every value): ragged m leaves a partial
+#: last m-tile, ragged rows a padded row block, "wrap" puts the logical
+#: cells across the int32 wrap point
+DENSE_CASES = {
+    "m_ragged": (32, 300, 8, 128, 1000),
+    "rows_ragged": (37, 256, 16, 128, 1000),
+    "wrap": (37, 300, 8, 128, INT32_MAX - 60),
+}
+
+
+def _assert_result_bits(got, want):
+    for key, w in want.items():
+        g = np.atleast_1d(np.asarray(got[key]))
+        w = np.atleast_1d(np.asarray(w))
+        assert g.dtype == w.dtype and g.shape == w.shape, (key, g, w)
+        np.testing.assert_array_equal(g.view(np.uint8), w.view(np.uint8),
+                                      err_msg=key)
+
+
+@pytest.mark.parametrize("case", sorted(DENSE_CASES))
+@pytest.mark.parametrize("kind", ["packed", "i32"])
+def test_one_vs_many_rows_match_plain_reference(kind, case):
+    n, m, bn, bm, lo = DENSE_CASES[case]
+    q, u8, base = _ordered_rows(np.random.default_rng(11), n, m, lo)
+    logical = _wrap32(u8.astype(np.int64) + base[:, None])
+    if kind == "packed":
+        got = ops.classify_packed(jnp.asarray(q), jnp.asarray(u8),
+                                  jnp.asarray(base), bn=bn, bm=bm,
+                                  interpret=True)
+    else:
+        got = ops.classify_i32(jnp.asarray(q), jnp.asarray(logical),
+                               bn=bn, bm=bm, interpret=True)
+    want = _ovm_reference(q, logical, bm)
+    assert want["q_le_p"].any() and want["p_le_q"].any()
+    _assert_result_bits(got, want)
+
+
+@pytest.mark.parametrize("case", ["hot_ragged", *sorted(DENSE_CASES)])
+def test_hybrid_rows_match_plain_reference(case):
+    rng = np.random.default_rng(12)
+    H, V = 13, 40
+    n, m, bn, bm, lo = DENSE_CASES.get(case, DENSE_CASES["rows_ragged"])
+    if case == "hot_ragged":
+        H, bn = 21, 8
+    q, u8, base = _ordered_rows(rng, n, m, lo)
+    logical = _wrap32(u8.astype(np.int64) + base[:, None])
+    rows = np.stack([rng.integers(V - 3, V + 4, H),
+                     rng.integers(0, 2, H)]).astype(np.int32)
+    hot_sums = (4 * rows.sum(axis=0)).astype(np.float32)
+    got, (bn_run, _) = ops.classify_hybrid(
+        jnp.asarray(q), V, rows, hot_sums, jnp.asarray(u8),
+        jnp.asarray(base), bn=bn, bm=bm, interpret=True)
+    assert H % bn_run, "the hot set must end inside a row block"
+    tail = _ovm_reference(q, logical, bm)
+    v, npriv = rows
+    zero = np.zeros(H, np.float32)
+    want = {
+        "q_le_p": np.concatenate([V <= v, tail["q_le_p"]]),
+        "p_le_q": np.concatenate([(v <= V) & (npriv == 0), tail["p_le_q"]]),
+        "sum_q": tail["sum_q"],
+        "sum_p": np.concatenate([hot_sums, tail["sum_p"]]),
+        "fp_q_before_p": np.concatenate([zero, tail["fp_q_before_p"]]),
+        "fp_p_before_q": np.concatenate([zero, tail["fp_p_before_q"]]),
+    }
+    assert want["q_le_p"][:H].any() and want["p_le_q"][:H].any()
+    _assert_result_bits(got, want)
 
 
 def test_engine_specs_all_valid_and_distinct():

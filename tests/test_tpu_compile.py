@@ -88,7 +88,7 @@ def _hybrid(S, H=4096, T=65536, m=512):
     def fn(q, meta, hot_sums, tail, base):
         return ops.classify_hybrid(q, 7, meta, hot_sums, tail, base, **OVM,
                                    interpret=False)[0]
-    return fn, (S((m,), jnp.int32), S((H, 2), jnp.int32),
+    return fn, (S((m,), jnp.int32), S((2, H), jnp.int32),
                 S((H,), jnp.float32), S((T, m), jnp.uint8),
                 S((T,), jnp.int32))
 
@@ -126,15 +126,18 @@ NAMES = {
 }
 
 
-def _kernel_calls(one_chip, case) -> list:
-    """The HLO lines of the Pallas calls the case compiles to."""
+def _compiled_text(one_chip, cases, case) -> str:
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    fn, args = CASES[case](S)
-    compiled = jax.jit(fn).lower(*args).compile()
-    return [line for line in compiled.as_text().splitlines()
-            if "tpu_custom_call" in line]
+    fn, args = cases[case](S)
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernel_calls(one_chip, case, cases=CASES) -> list:
+    """The HLO lines of the Pallas calls the case compiles to."""
+    return [line for line in _compiled_text(one_chip, cases, case)
+            .splitlines() if "tpu_custom_call" in line]
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -164,3 +167,39 @@ def test_chip_blocks_are_the_compiled_ones(op, engine, blocks):
     eng = CausalEngine(CausalPolicy(autotune=False))
     assert eng.blocks(op, 1 << 20, 4096, 1024, interpret=False,
                       engine=engine) == blocks
+
+
+#: the two cells' sweeps at full size: the fleet registry's one-vs-many
+#: over 1,048,576 rows, and the session store's hybrid over 65,536 hot
+#: rows and a 983,040-row tail, both at m = 1,024
+SWEEPS = {
+    "one_vs_many_u8": lambda S: (
+        lambda q, p, b: ops.classify_packed(q, p, b, **OVM,
+                                            interpret=False),
+        (S((1024,), jnp.int32), S((1 << 20, 1024), jnp.uint8),
+         S((1 << 20,), jnp.int32))),
+    "hybrid": lambda S: _hybrid(S, H=65536, T=983040, m=1024),
+}
+
+#: a 2-D shape of 65,536 or more rows whose minor dimension is 1 or 2:
+#: on the chip such a column is padded to 128 lanes a row
+_NARROW = re.compile(r"\b[a-z]+\d*\[(\d+),([12])\]")
+
+
+def _narrow_columns(text: str) -> list:
+    return [m.group(0) for m in _NARROW.finditer(text)
+            if int(m.group(1)) >= 65536]
+
+
+@pytest.mark.parametrize("case", sorted(SWEEPS))
+def test_sweep_has_no_lane_padded_columns(one_chip, case):
+    """Per-row vectors cross the kernel boundary, and every XLA
+    operation around it, as dense rows: no base, metadata, flag, sum or
+    Eq. 3 column is laid out one row per 128 lanes."""
+    text = _compiled_text(one_chip, SWEEPS, case)
+    assert not _narrow_columns(text), sorted(set(_narrow_columns(text)))
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1, calls
+    assert re.match(rf"\s*(ROOT )?%{NAMES[case]}(\.\d+)? = ", calls[0])
+    assert not _narrow_columns(calls[0]), calls[0]
